@@ -47,7 +47,9 @@ def main() -> None:
     ap.add_argument("--full", action="store_true", help="paper-scale sizes")
     ap.add_argument("--only", default=None, help="substring filter on module")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = []
     for mod_name in MODULES:

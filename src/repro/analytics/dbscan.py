@@ -30,7 +30,9 @@ UNVISITED = -2
 def _radius_block(xq: jax.Array, x: jax.Array, eps2: jax.Array) -> jax.Array:
     sq_q = jnp.sum(xq * xq, axis=1, keepdims=True)
     sq_x = jnp.sum(x * x, axis=1)
-    d2 = sq_q + sq_x[None, :] - 2.0 * xq @ x.T
+    d2 = sq_q + sq_x[None, :] - 2.0 * jnp.matmul(
+        xq, x.T, precision=jax.lax.Precision.HIGHEST
+    )
     return d2 <= eps2
 
 

@@ -20,7 +20,11 @@ import numpy as np
 def _kde_block(xq: jax.Array, x: jax.Array, inv_two_h2: jax.Array) -> jax.Array:
     sq_q = jnp.sum(xq * xq, axis=1, keepdims=True)
     sq_x = jnp.sum(x * x, axis=1)
-    d2 = jnp.maximum(sq_q + sq_x[None, :] - 2.0 * xq @ x.T, 0.0)
+    d2 = jnp.maximum(
+        sq_q + sq_x[None, :]
+        - 2.0 * jnp.matmul(xq, x.T, precision=jax.lax.Precision.HIGHEST),
+        0.0,
+    )
     return jnp.mean(jnp.exp(-d2 * inv_two_h2), axis=1)
 
 

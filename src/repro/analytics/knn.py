@@ -47,7 +47,9 @@ def _nn_block(
       k-independent term there (see the e2e test's slack comment)."""
     sq_q = jnp.sum(xq * xq, axis=1, keepdims=True)
     sq_x = jnp.sum(x * x, axis=1)
-    d2 = sq_q + sq_x[None, :] - 2.0 * xq @ x.T  # (b, m)
+    d2 = sq_q + sq_x[None, :] - 2.0 * jnp.matmul(
+        xq, x.T, precision=jax.lax.Precision.HIGHEST
+    )  # (b, m)
     rows = start + jnp.arange(xq.shape[0])
     if use_top_k:
         neg_vals, idx = jax.lax.top_k(-d2, 2)  # two smallest per row

@@ -111,7 +111,9 @@ def _tile_d2(xqt, sq_q, x, sq_x, j, bk, m, col_offset):
             d2 = d2 + diff * diff
     else:
         sq_t = lax.dynamic_slice(sq_x, (j * bk,), (bk,))
-        d2 = sq_q + sq_t[None, :] - 2.0 * xqt @ xt.T
+        d2 = sq_q + sq_t[None, :] - 2.0 * jnp.matmul(
+            xqt, xt.T, precision=lax.Precision.HIGHEST
+        )
     d2 = jnp.where(cols[None, :] >= m, jnp.inf, d2)
     return d2, cols
 

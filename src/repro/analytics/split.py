@@ -47,7 +47,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.bucketing import DEFAULT_BUCKETS, ShapeBucketCache, round_up
 from repro.analytics.pairwise import (
     DEFAULT_BLOCK,
@@ -233,7 +232,7 @@ def _mesh_fn(
             if task == "dbscan"
             else (P("d", "q"), P("d", "q"))
         )
-        return shard_map(
+        return jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(P("q", None), P("d", None), P(), P()),
@@ -401,10 +400,16 @@ def split_pairwise_dbscan(
         from repro.kernels.pairwise_reduce.ops import (
             pairwise_dbscan_split_reduce,
         )
-
-        xq_pad, x_sh, mk_pad_seq = _split_prepare(
-            x, None, shards, bq, bk, bucket
+        from repro.kernels.pairwise_reduce.pairwise_reduce import (
+            DBSCAN_BLOCK_K,
         )
+
+        # the kernel's packed-word blocks tile only at whole DBSCAN_BLOCK_K
+        # dataset tiles; the merge trims its wider all-zero padding words
+        # back to the sequential width
+        mk_pad_seq = bucket.bucket_tile_rows(m, bk)
+        bk = round_up(bk, DBSCAN_BLOCK_K)
+        xq_pad, x_sh, _ = _split_prepare(x, None, shards, bq, bk, bucket)
         counts_p, packed_p = jax.device_get(
             pairwise_dbscan_split_reduce(
                 xq_pad, x_sh.reshape(-1, x_sh.shape[2]), m, eps2, shards,

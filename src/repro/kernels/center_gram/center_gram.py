@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _center_gram_kernel(xi_ref, xj_ref, o_ref, g_ref, si_ref, sj_ref, *, nm: int, m: int):
     @pl.when(pl.program_id(2) == 0)
@@ -81,7 +79,7 @@ def center_gram_pallas(
             pltpu.VMEM((1, bd), jnp.float32),
             pltpu.VMEM((1, bd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

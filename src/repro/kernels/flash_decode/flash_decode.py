@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -99,7 +97,7 @@ def flash_decode_pallas(
             pltpu.VMEM((kv, g), jnp.float32),       # running denom
             pltpu.VMEM((kv, g, hd), jnp.float32),   # running numerator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

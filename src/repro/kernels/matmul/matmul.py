@@ -18,16 +18,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, nk: int):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # f32 operands get full f32 products (the Halko fit compares against a
+    # HIGHEST-precision jnp path); Mosaic takes no precision for bf16
+    f32 = a_ref.dtype == jnp.float32
     acc_ref[...] += jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=jnp.float32
+        a_ref[...], b_ref[...], preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST if f32 else None,
     )
 
     @pl.when(pl.program_id(2) == nk - 1)
@@ -75,7 +77,7 @@ def matmul_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

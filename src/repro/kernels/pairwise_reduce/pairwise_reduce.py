@@ -9,7 +9,8 @@ mirroring ``kernels/pairwise_tlb``):
 
 * ``pairwise_knn_pallas``    — running (min-d2, argmin), self excluded;
 * ``pairwise_dbscan_pallas`` — eps-ball degree counts (carried) + packed
-                               uint32 neighbor bitmasks (tile-local write);
+                               uint32 neighbor bitmasks (tile-local write,
+                               word-major so each block is (8k, 128)-tiled);
 * ``pairwise_kde_pallas``    — compensated (Neumaier) Gaussian exp-sum pair.
 
 Each kernel also has a ``*_split_pallas`` variant with a LEADING 'parallel'
@@ -36,8 +37,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _tile_d2(xq_ref, x_ref, row0, col0, m, bq, bk):
     """(bq, bk) squared-distance tile with global row/col ids; padded
@@ -49,7 +48,8 @@ def _tile_d2(xq_ref, x_ref, row0, col0, m, bq, bk):
     sq_q = jnp.sum(xqt * xqt, axis=1, keepdims=True)
     sq_t = jnp.sum(xt * xt, axis=1)
     d2 = sq_q + sq_t[None, :] - 2.0 * jnp.dot(
-        xqt, xt.T, preferred_element_type=jnp.float32
+        xqt, xt.T, preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     cols = col0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
@@ -103,13 +103,46 @@ def _knn_split_kernel(
 def pack_bits_u32(mask: jax.Array) -> jax.Array:
     """(rows, cols) bool -> (rows, cols//32) uint32, little-endian bit order
     (bit j of word w flags column w*32 + j). THE bit-layout definition for
-    this package: the kernel body and the ref oracle both pack through it,
-    and the engine's jnp tile body (``analytics.pairwise._pack_bits``)
-    mirrors it — cross-path agreement is pinned by the parity sweeps."""
+    this package: the ref oracle packs through it, the kernel body computes
+    the same words on the MXU (``_pack_words_t``), and the engine's jnp tile
+    body (``analytics.pairwise._pack_bits``) mirrors it — cross-path
+    agreement is pinned by the parity sweeps."""
     rows, cols = mask.shape
     u = mask.astype(jnp.uint32).reshape(rows, cols // 32, 32)
     weights = jnp.left_shift(jnp.uint32(1), jnp.arange(32, dtype=jnp.uint32))
     return jnp.sum(u * weights[None, None, :], axis=-1, dtype=jnp.uint32)
+
+
+# dataset tile of the DBSCAN kernels: its bk // 32 = 8 packed words fill
+# the sublane side of one (8, 128) int32 output tile (word-major layout)
+DBSCAN_BLOCK_K = 256
+
+
+def _pack_words_t(mask: jax.Array) -> jax.Array:
+    """(bq, bk) bool -> (bk // 32, bq) int32: ``pack_bits_u32(mask).T``
+    as raw bits, computed on the MXU. Word w of row r is the dot of the
+    row with weights 2^(c % 32) on columns c of word w, split into 16-bit
+    halves: bf16 holds 0/1 and every power of two exactly, and an f32
+    accumulator holds any sum below 2^24 exactly, so the packing is exact.
+    The transposed product keeps the word axis off the lane dimension (a
+    (bq, bk // 32) block is not (8, 128)-tiled for any bk below 4096)."""
+    _, bk = mask.shape
+    shape = (bk // 32, bk)
+    word = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    bit = col % 32
+    own = col // 32 == word
+    weight = jnp.left_shift(1, bit % 16).astype(jnp.float32)
+    bits = mask.astype(jnp.bfloat16)
+
+    def half(sel):
+        w = jnp.where(own & sel, weight, 0.0).astype(jnp.bfloat16)
+        return jax.lax.dot_general(
+            w, bits, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ).astype(jnp.int32)
+
+    return half(bit < 16) | jnp.left_shift(half(bit >= 16), 16)
 
 
 def _dbscan_body(xq_ref, x_ref, cnt_ref, packed_ref, acc_cnt, row0, col0, j, m, bq, bk, eps2):
@@ -121,7 +154,7 @@ def _dbscan_body(xq_ref, x_ref, cnt_ref, packed_ref, acc_cnt, row0, col0, j, m, 
     mask = d2 <= eps2  # self included (d2=0); the host BFS drops it
     acc_cnt[...] += jnp.sum(mask, axis=1, keepdims=True, dtype=jnp.int32)
     cnt_ref[...] = acc_cnt[...]
-    packed_ref[...] = pack_bits_u32(mask)
+    packed_ref[...] = _pack_words_t(mask)
 
 
 def _dbscan_kernel(xq_ref, x_ref, cnt_ref, packed_ref, acc_cnt, *, m, bq, bk, eps2):
@@ -238,7 +271,7 @@ def pairwise_knn_pallas(
             pltpu.VMEM((bq, 1), jnp.float32),  # running min d2
             pltpu.VMEM((bq, 1), jnp.int32),  # running argmin
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -256,10 +289,13 @@ def pairwise_dbscan_pallas(
     m: int,
     eps2: float,
     block_q: int = 128,
-    block_k: int = 128,
+    block_k: int = DBSCAN_BLOCK_K,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """-> (eps-ball counts (mq,) int32, packed bitmask (mq, mk_pad/32))."""
+    """-> (eps-ball counts (mq,) int32, packed bitmask (mq, mk_pad/32)).
+
+    On TPU ``block_k`` must be a multiple of ``DBSCAN_BLOCK_K`` (or cover
+    the whole padded dataset); interpret mode takes any multiple of 32."""
     mq = xq.shape[0]
     bq = min(block_q, max(mq, 1))
     bk = max(32, (block_k // 32) * 32)  # packed words divide the tile
@@ -273,21 +309,22 @@ def pairwise_dbscan_pallas(
         in_specs=in_specs,
         out_specs=(
             pl.BlockSpec((bq, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((bq, bk // 32), lambda i, j: (i, j)),
+            pl.BlockSpec((bk // 32, bq), lambda i, j: (j, i)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((xq.shape[0], 1), jnp.int32),
-            jax.ShapeDtypeStruct((xq.shape[0], w), jnp.uint32),
+            jax.ShapeDtypeStruct((w, xq.shape[0]), jnp.int32),
         ),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.int32),  # running degree count
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(xq, x)
-    return cnt[:mq, 0], packed[:mq]
+    packed = jax.lax.bitcast_convert_type(packed, jnp.uint32)
+    return cnt[:mq, 0], packed.T[:mq]
 
 
 @functools.partial(
@@ -326,7 +363,7 @@ def pairwise_kde_pallas(
             pltpu.VMEM((bq, 1), jnp.float32),  # running exp-sum
             pltpu.VMEM((bq, 1), jnp.float32),  # running compensation
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -397,7 +434,7 @@ def pairwise_knn_split_pallas(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -420,7 +457,7 @@ def pairwise_dbscan_split_pallas(
     eps2: float,
     shards: int,
     block_q: int = 128,
-    block_k: int = 128,
+    block_k: int = DBSCAN_BLOCK_K,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """-> ((shards, mq) int32 counts, (shards, mq, shard_words) uint32)."""
@@ -431,6 +468,7 @@ def pairwise_dbscan_split_pallas(
         xq, x, shards, bq, bk
     )
     w = shard_rows // 32
+    tps = shard_rows // bk
     cnt, packed = pl.pallas_call(
         functools.partial(
             _dbscan_split_kernel,
@@ -441,25 +479,26 @@ def pairwise_dbscan_split_pallas(
         out_specs=(
             pl.BlockSpec((bq, 1), lambda s, i, j, nq=nq: (s * nq + i, 0)),
             pl.BlockSpec(
-                (bq, bk // 32), lambda s, i, j, nq=nq: (s * nq + i, j)
+                (bk // 32, bq), lambda s, i, j, tps=tps: (s * tps + j, i)
             ),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((shards * xq.shape[0], 1), jnp.int32),
-            jax.ShapeDtypeStruct((shards * xq.shape[0], w), jnp.uint32),
+            jax.ShapeDtypeStruct((shards * w, xq.shape[0]), jnp.int32),
         ),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(xq, x)
     mq_pad = xq.shape[0]
+    packed = jax.lax.bitcast_convert_type(packed, jnp.uint32)
     return (
         cnt.reshape(shards, mq_pad)[:, :mq],
-        packed.reshape(shards, mq_pad, w)[:, :mq],
+        packed.reshape(shards, w, mq_pad).transpose(0, 2, 1)[:, :mq],
     )
 
 
@@ -505,7 +544,7 @@ def pairwise_kde_split_pallas(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

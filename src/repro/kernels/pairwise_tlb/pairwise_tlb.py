@@ -2,7 +2,7 @@
 
 For P sampled pairs and a (d, K) PCA basis V, computes the (P, K) table
     tlb[p, k] = ||(x_i - x_j) @ V[:, :k+1]|| / ||x_i - x_j||
-in ONE pass: diff -> project (MXU) -> square -> prefix-cumsum -> normalize.
+in ONE pass: diff -> project (MXU) -> square -> prefix-sum (MXU) -> normalize.
 This is the TPU-native replacement for the paper's per-k TLB evaluations
 (DESIGN.md §2): binary search over k collapses into reading this table.
 
@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _tlb_kernel(xi_ref, xj_ref, v_ref, o_ref, acc_ref, den_ref):
     diffs = (xi_ref[...] - xj_ref[...]).astype(jnp.float32)
@@ -34,10 +32,19 @@ def _tlb_kernel(xi_ref, xj_ref, v_ref, o_ref, acc_ref, den_ref):
         den_ref[...] = jnp.sum(diffs * diffs, axis=1, keepdims=True)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    hi = jax.lax.Precision.HIGHEST
     z = jnp.dot(diffs, v_ref[...].astype(jnp.float32),
-                preferred_element_type=jnp.float32)  # (bp, bk)
+                preferred_element_type=jnp.float32, precision=hi)  # (bp, bk)
     zsq = z * z
-    cum = jnp.cumsum(zsq, axis=1) + acc_ref[...]
+    # prefix sum along the tile as a matmul with an upper-triangular ones
+    # matrix: Mosaic has no cumsum lowering, and the MXU does this for free
+    bk = zsq.shape[1]
+    upper = (
+        jax.lax.broadcasted_iota(jnp.int32, (bk, bk), 0)
+        <= jax.lax.broadcasted_iota(jnp.int32, (bk, bk), 1)
+    ).astype(jnp.float32)
+    cum = jnp.dot(zsq, upper, preferred_element_type=jnp.float32,
+                  precision=hi) + acc_ref[...]
     acc_ref[...] += jnp.sum(zsq, axis=1, keepdims=True)
     den = den_ref[...]
     tlb = jnp.sqrt(jnp.clip(cum / jnp.maximum(den, 1e-30), 0.0, 1.0))
@@ -83,7 +90,7 @@ def pairwise_tlb_pallas(
             pltpu.VMEM((bp, 1), jnp.float32),  # running sum of z^2 per pair
             pltpu.VMEM((bp, 1), jnp.float32),  # ||diff||^2 per pair
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

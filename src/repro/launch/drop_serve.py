@@ -76,6 +76,7 @@ _force_host_devices_from_argv()
 
 import numpy as np  # noqa: E402
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core import DropConfig, reduce  # noqa: E402
 from repro.core.cost import downstream_cost  # noqa: E402
 from repro.core.reducer import REDUCER_METHODS  # noqa: E402
@@ -323,6 +324,7 @@ def main() -> None:
     ap.add_argument("--compare-sequential", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     datasets = build_workload(
         args.queries, max(1, min(args.datasets, args.queries)),
@@ -498,7 +500,8 @@ def main() -> None:
               f"split={args.analytics_split or 1}, "
               f"fanout={svc.analytics_fanout})")
     for r in results:
-        tag = ("DEAD" if r.error == "deadline" else "DEGR" if r.degraded
+        tag = ("DEAD" if r.error == "deadline" else "ERR " if r.error
+               else "DEGR" if r.degraded
                else "SUFX" if r.suffix_update else "HIT " if r.cache_hit
                else "WARM" if r.warm_started else "COLD")
         where = f" @{r.worker}" if r.worker else ""
@@ -512,6 +515,12 @@ def main() -> None:
               f"wall={r.wall_s*1e3:7.1f} ms{ds}{where}")
     if args.fleet:
         svc.shutdown()
+    # an expired deadline is a served answer under the SLO; any other error
+    # is a failure the exit code must carry
+    failed = [r for r in results if r.error not in (None, "deadline")]
+    if failed:
+        sys.exit(f"{len(failed)} queries failed: "
+                 + "; ".join(f"q{r.query_id} {r.error}" for r in failed[:5]))
 
     if args.compare_sequential:
         seq_cost = cost or downstream_cost(args.downstream, args.rows)

@@ -189,8 +189,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
             compiled = lowered.compile()
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, list):  # jax<=0.4.x: one dict per device
-            cost = cost[0] if cost else {}
         roof = build_roofline(cfg, shape, mesh_name, chips, compiled)
         record.update(
             status="ok",

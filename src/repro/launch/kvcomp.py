@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.launch.dryrun import params_struct
 from repro.models.layers import apply_mrope, apply_rope, rms_norm
@@ -49,7 +48,7 @@ def flash_decode_compressed(qc, ck, cv, basis_v, valid, ctx: ShardCtx, hd: int):
     if ctx.mesh is None:
         return local(qc, ck, cv, valid, basis_v)
     ba, sa = tuple(batch_axes), tuple(seq_axes)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=ctx.mesh,
         in_specs=(P(ba, None, None, None, None), P(ba, sa, None, None),

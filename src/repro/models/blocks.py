@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models.attention import attention, init_attention
 from repro.models.mlp import gelu_mlp, init_gelu_mlp, init_swiglu, swiglu
 from repro.models.moe import init_moe, moe_ffn
@@ -156,7 +155,7 @@ def _moe_sublayer(x, params, cfg, ctx: ShardCtx):
             aux = jax.lax.psum(aux, dp) / n
         return y.reshape(bl, sl, d), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(dp, None, None), moe_specs),

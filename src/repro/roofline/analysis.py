@@ -81,8 +81,6 @@ def build_roofline(
 ) -> Roofline:
     totals = analyze(compiled.as_text())
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, list):  # jax<=0.4.x: one dict per device
-        ca = ca[0] if ca else {}
     mem = compiled.memory_analysis()
     mem_stats = {
         "argument_bytes": getattr(mem, "argument_size_in_bytes", 0),

@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.models.attention import decode_attention
 from repro.models.layers import apply_mrope, apply_rope, rms_norm
@@ -98,7 +97,7 @@ def flash_decode(
 
     ba = tuple(batch_axes)
     sa = tuple(seq_axes)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=ctx.mesh,
         in_specs=(

@@ -110,6 +110,59 @@ _WORKER_BOOT = (
 )
 
 
+# --------------------------------------------------------- chip ownership
+
+
+def _visible_tpu_chips() -> int:
+    """TPU chips attached to this host, counted from their device files
+    (``/dev/accel<N>``, or ``/dev/vfio/<N>`` under the vfio driver) so the
+    count never opens a chip itself."""
+    import glob
+
+    accel = glob.glob("/dev/accel[0-9]*")
+    if accel:
+        return len(accel)
+    return len([p for p in glob.glob("/dev/vfio/*") if p.rsplit("/", 1)[-1].isdigit()])
+
+
+def _process_holds_tpu() -> bool:
+    """Whether this process already opened a TPU client. Asked without
+    initializing JAX: a process that never imported it, or whose backends are
+    not up yet, holds nothing."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized() and "tpu" in getattr(
+        xla_bridge, "_backends", {}
+    )
+
+
+def _check_chip_ownership(workers: int) -> None:
+    """Refuse a fleet the host's chips cannot carry, before any worker
+    starts. A TPU chip belongs to one process at a time, and each worker
+    opens the chips of the host it starts on: a worker behind a parent that
+    holds the chip, or one beyond the host's chip count, fails or hangs in
+    start-up. Workers held off the chip by ``JAX_PLATFORMS`` (the CPU fleet)
+    are never refused."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return
+    if _process_holds_tpu():
+        raise RuntimeError(
+            "FleetSupervisor: this process already holds the TPU, so a "
+            "worker process cannot open it; start the fleet before any JAX "
+            "computation in this process, or serve in-process (DropService)"
+        )
+    chips = _visible_tpu_chips()
+    if chips and workers > chips:
+        raise RuntimeError(
+            f"FleetSupervisor: {workers} worker processes but {chips} TPU "
+            f"chip(s) on this host; one process owns a chip, so start at "
+            f"most {chips} worker(s)"
+        )
+
+
 # ------------------------------------------------------------------ framing
 
 
@@ -758,6 +811,7 @@ class FleetSupervisor:
     def start(self) -> "FleetSupervisor":
         if self._started:
             return self
+        _check_chip_ownership(len(self._workers))
         self._started = True
         for w in self._workers:
             self._spawn(w)

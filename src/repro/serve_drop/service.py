@@ -142,6 +142,9 @@ class ServiceStats:
     fit_calls: int = 0
     iterations: int = 0
     validation_pairs: int = 0
+    # cache-hit revalidations that RAISED (the query then refits cold; a
+    # nonzero count means a broken validation path, not data drift)
+    validation_errors: int = 0
     suffix_updates: int = 0  # queries served by an incremental merge
     suffix_update_failures: int = 0  # updates that fell through (or raised)
     downstream_runs: int = 0  # served analytics executions (execute_downstream)
@@ -1331,7 +1334,8 @@ class DropService:
             passed, result = self._validate(val)
         except Exception:
             # a broken entry must not serve — but an infrastructure error is
-            # NOT a drift observation, so it stays out of the TTL tuner
+            # NOT a drift observation, so it stays out of the TTL tuner; it
+            # is counted, so the cold refit it falls to never hides it
             passed, result, errored = False, None, True
         q = val.query
         new_tracker = None
@@ -1347,7 +1351,9 @@ class DropService:
                 new_tracker = None  # re-register without updater state
         with self._lock:
             self._stepping_now.remove(val)
-            if not errored:
+            if errored:
+                self.stats.validation_errors += 1
+            else:
                 self.cache.note_validation(passed)
             self.stats.effective_ttl = self.cache.ttl_ticks
             if passed:

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.models.model import loss_fn
 from repro.sharding.specs import ShardCtx
@@ -148,7 +147,7 @@ def make_train_step(
         return loss, metrics, grads_red, new_resid
 
     def train_step_compressed(params, opt_state, batch, residual):
-        loss, metrics, grads, residual = shard_map(
+        loss, metrics, grads, residual = jax.shard_map(
             per_pod,
             mesh=mesh,
             in_specs=(P(), jax.tree_util.tree_map(lambda _: P("pod"), batch), P("pod")),

@@ -104,6 +104,54 @@ def test_cache_hit_result_is_valid_basis():
     assert np.all(d_lo <= d_hi + 1e-3)
 
 
+def test_raising_validation_is_counted_not_hidden(monkeypatch):
+    """A revalidation that raises still falls back to a cold refit (the
+    query is served), but the error lands in ``stats.validation_errors``
+    instead of passing as an ordinary miss."""
+    (x,) = _datasets(1)
+    svc = DropService()
+    svc.submit(x, CFG, zero_cost())
+    svc.run()
+    assert svc.stats.validation_errors == 0
+
+    def boom(val):
+        raise RuntimeError("validation path broke")
+
+    monkeypatch.setattr(svc, "_validate", boom)
+    fits = svc.stats.fit_calls
+    svc.submit(x, CFG, zero_cost())
+    r = svc.run()[0]
+    assert r.error is None and not r.cache_hit and r.result.satisfied
+    assert svc.stats.validation_errors == 1
+    assert svc.stats.fit_calls > fits  # served by the cold refit
+    assert svc.stats.failures == 0
+
+
+def test_launcher_exits_nonzero_when_a_query_fails(monkeypatch, capsys):
+    """launch/drop_serve.py prints every result, then carries a failed
+    query (anything but an expired deadline) in its exit status."""
+    import sys
+
+    from repro.launch import drop_serve
+    from repro.pipeline import optimizer
+
+    def boom(*a, **k):
+        raise RuntimeError("planted")
+
+    monkeypatch.setattr(optimizer, "run_downstream", boom)
+    # the persistent compile cache is process-wide; keep it off here
+    monkeypatch.setattr(drop_serve, "enable_compile_cache", lambda: "")
+    monkeypatch.setattr(sys, "argv", [
+        "drop_serve", "--queries", "2", "--rows", "200",
+        "--execute-downstream",
+    ])
+    with pytest.raises(SystemExit) as exc:
+        drop_serve.main()
+    assert "2 queries failed" in str(exc.value.code)
+    assert "RuntimeError: planted" in str(exc.value.code)
+    assert capsys.readouterr().out.count("[ERR ]") == 2
+
+
 def test_concurrent_repeats_deduplicated():
     """Repeats submitted concurrently with their first instance must not all
     run cold: the scheduler defers them onto the cache."""

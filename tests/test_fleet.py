@@ -89,6 +89,27 @@ def test_cost_spec_named_and_rejected():
 # ------------------------------------------------------- supervised serve
 
 
+@pytest.mark.parametrize(
+    "workers,chips,holds,match",
+    [
+        (2, 1, False, "2 worker processes but 1 TPU chip"),
+        (1, 1, True, "already holds the TPU"),
+    ],
+)
+def test_fleet_refuses_chips_it_cannot_own(monkeypatch, workers, chips, holds, match):
+    """On a TPU host, start() raises before spawning anything when the
+    caller holds the chip or workers outnumber chips — never a hang."""
+    from repro.serve_drop import fleet as fleet_mod
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(fleet_mod, "_visible_tpu_chips", lambda: chips)
+    monkeypatch.setattr(fleet_mod, "_process_holds_tpu", lambda: holds)
+    sup = FleetSupervisor(workers=workers, profile=False)
+    with pytest.raises(RuntimeError, match=match):
+        sup.start()
+    assert all(w.proc is None for w in sup._workers)
+
+
 def test_fleet_round_trip_matches_inprocess():
     """Two workers serve three tenants; per-query k matches the in-process
     service (same scheduler inside every worker)."""

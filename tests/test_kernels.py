@@ -73,6 +73,18 @@ def test_pairwise_tlb_kernel_matches_ref(p, d, kdim, dtype):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=tol, atol=tol)
 
 
+def test_pairwise_tlb_kernel_chip_tiles_kmax_over_128():
+    """The default (chip) tiles with kmax > 128: the MXU prefix sum carries
+    across two K tiles and matches the cumsum oracle."""
+    kx, ky, kv = jax.random.split(jax.random.PRNGKey(14), 3)
+    xi = jax.random.normal(kx, (40, 192))
+    xj = jax.random.normal(ky, (40, 192))
+    v = jnp.linalg.qr(jax.random.normal(kv, (192, 192)))[0][:, :160]
+    got = pairwise_tlb_pallas(xi, xj, v, interpret=True)
+    want = pairwise_tlb_ref(xi, xj, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
 def test_pairwise_tlb_kernel_coincident_pair_is_one():
     x = jnp.ones((8, 16), jnp.float32)
     v = jnp.eye(16)[:, :8]
@@ -191,6 +203,34 @@ def test_pairwise_dbscan_kernel_matches_ref(mq, mk, d):
     np.testing.assert_array_equal(np.asarray(gc), np.asarray(rc))
     # widths differ by padding; the extra words must be all-zero
     gp, rp = np.asarray(gp), np.asarray(rp)
+    w = min(gp.shape[1], rp.shape[1])
+    np.testing.assert_array_equal(gp[:, :w], rp[:, :w])
+    assert not gp[:, w:].any() and not rp[:, w:].any()
+
+
+@pytest.mark.parametrize("shards", [None, 2])
+def test_pairwise_dbscan_kernel_chip_tiles_match_ref(shards):
+    """The chip's tile layout (bq=128, bk=DBSCAN_BLOCK_K, word-major packed
+    blocks) over several query and dataset tiles, bit-exact against the
+    oracle; dense enough that every packed word carries set bits."""
+    from repro.kernels.pairwise_reduce.pairwise_reduce import DBSCAN_BLOCK_K
+
+    mq, mk = 200, 600
+    x = _rand(jax.random.PRNGKey(15), (mk, 3), jnp.float32)
+    eps2 = 1.2 ** 2
+    if shards is None:
+        gc, gp = pairwise_dbscan_pallas(x[:mq], x, mk, eps2, interpret=True)
+    else:
+        from repro.analytics.split import merge_dbscan_partials
+
+        xp = _shard_pad(x, shards, DBSCAN_BLOCK_K)
+        gc, gp = merge_dbscan_partials(*pairwise_dbscan_split_pallas(
+            x[:mq], xp, mk, eps2, shards, interpret=True
+        ))
+    rc, rp = pairwise_dbscan_ref(x[:mq], x, mk, eps2)
+    np.testing.assert_array_equal(np.asarray(gc), np.asarray(rc))
+    gp, rp = np.asarray(gp), np.asarray(rp)
+    assert (rp[:, : mk // 32] != 0).mean() > 0.5
     w = min(gp.shape[1], rp.shape[1])
     np.testing.assert_array_equal(gp[:, :w], rp[:, :w])
     assert not gp[:, w:].any() and not rp[:, w:].any()

@@ -24,10 +24,7 @@ def _scan_model(n_layers, b=16, d=64):
 
 
 def _cost_flops(compiled) -> float:
-    ca = compiled.cost_analysis()
-    if isinstance(ca, list):  # jax<=0.4.x: one dict per device
-        ca = ca[0]
-    return ca["flops"]
+    return compiled.cost_analysis()["flops"]
 
 
 def test_cost_analysis_misses_scan_trips():
