@@ -25,6 +25,7 @@ import numpy as np
 from scipy import stats
 
 from repro.core.bucketing import ShapeBucketCache
+from repro.obs import span
 
 
 def sample_pairs(m: int, p: int, rng: np.random.Generator) -> np.ndarray:
@@ -130,6 +131,7 @@ class TLBEstimate:
     lo: float
     hi: float
     pairs_used: int
+    rounds: int = 0  # device calls (CI doublings) this estimate made
 
 
 class TLBEstimator:
@@ -158,26 +160,31 @@ class TLBEstimator:
         self._fn = _kernel_prefix_tlb if use_kernels else prefix_tlb_table
         self._pairs = np.zeros((0, 2), dtype=np.int32)
         self._table = np.zeros((0, int(v.shape[1])), dtype=np.float32)
+        self.rounds = 0  # device calls made: one per CI doubling
 
     def _extend(self, p: int) -> None:
         if p <= self._pairs.shape[0]:
             return
-        new = sample_pairs(self.m, p - self._pairs.shape[0], self.rng)
-        xi = self.x[new[:, 0]]
-        xj = self.x[new[:, 1]]
-        if self.bucket is not None:
-            # zero-pad the batch to its shape bucket: jit sees a bounded set of
-            # pair-batch shapes across doublings/queries; padded rows (diff 0)
-            # are sliced off below before they can touch the estimate
-            padded = self.bucket.bucket_pairs(new.shape[0])
-            if padded > new.shape[0]:
-                pad = np.zeros((padded - new.shape[0], xi.shape[1]), xi.dtype)
-                xi = np.concatenate([xi, pad], axis=0)
-                xj = np.concatenate([xj, pad], axis=0)
-        rows = np.asarray(self._fn(jnp.asarray(xi), jnp.asarray(xj), self.v))
-        rows = rows[: new.shape[0]]
-        self._pairs = np.concatenate([self._pairs, new], axis=0)
-        self._table = np.concatenate([self._table, rows], axis=0)
+        n = p - self._pairs.shape[0]
+        self.rounds += 1
+        with span("tlb.extend", pairs=n):
+            new = sample_pairs(self.m, n, self.rng)
+            xi = self.x[new[:, 0]]
+            xj = self.x[new[:, 1]]
+            if self.bucket is not None:
+                # zero-pad the batch to its shape bucket: jit sees a bounded
+                # set of pair-batch shapes across doublings/queries; padded
+                # rows (diff 0) are sliced off below before they can touch
+                # the estimate
+                padded = self.bucket.bucket_pairs(new.shape[0])
+                if padded > new.shape[0]:
+                    pad = np.zeros((padded - new.shape[0], xi.shape[1]), xi.dtype)
+                    xi = np.concatenate([xi, pad], axis=0)
+                    xj = np.concatenate([xj, pad], axis=0)
+            rows = np.asarray(self._fn(jnp.asarray(xi), jnp.asarray(xj), self.v))
+            rows = rows[: new.shape[0]]
+            self._pairs = np.concatenate([self._pairs, new], axis=0)
+            self._table = np.concatenate([self._table, rows], axis=0)
 
     def table(self, p: int) -> np.ndarray:
         """(p, kmax) TLB table over the first p sampled pairs."""
@@ -190,13 +197,14 @@ class TLBEstimator:
         """EVALUATE-TLB (Alg. 4 lines 11-18): double pairs until the CI clears
         the target (or the budget is exhausted). Uses only column k."""
         p = min(initial_pairs, max_pairs, self.num_pairs_total)
+        rounds0 = self.rounds
         while True:
             if k <= 0:
                 return TLBEstimate(0.0, 0.0, 0.0, 0)
             vals = self.table(p)[:, k - 1]
             mean, lo, hi = gaussian_ci(vals, self.confidence)
             if lo > target or hi < target or p >= min(max_pairs, self.num_pairs_total):
-                return TLBEstimate(mean, lo, hi, p)
+                return TLBEstimate(mean, lo, hi, p, self.rounds - rounds0)
             p = min(p * 2, max_pairs, self.num_pairs_total)
 
     def estimate_all_k(

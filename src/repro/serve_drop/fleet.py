@@ -1252,12 +1252,14 @@ class FleetSupervisor:
         self, x, cfg=None, cost=None, *, method: str = "pca",
         downstream: str | None = None, execute_downstream: bool = False,
         max_backlog: int | None = None, deadline_s: float | None = None,
-        fingerprint: str | None = None,
+        fingerprint: str | None = None, t_start: float | None = None,
     ) -> int | None:
         """Enqueue unless the fleet backlog is at ``max_backlog`` (ingest
         backpressure). The conversion/hash work runs on the submitter's
         thread, like ``DropService.try_submit`` (and like there, a caller
-        that already hashed the converted dataset passes ``fingerprint``).
+        that already hashed the converted dataset passes ``fingerprint``,
+        and one that began the submit earlier its start as ``t_start``,
+        from which ``stats.submit_s`` is booked).
         A NaN/Inf dataset finishes immediately with
         ``error="invalid_input"`` and never crosses a worker pipe."""
         import numpy as np
@@ -1267,6 +1269,8 @@ class FleetSupervisor:
 
         if not self._started:
             self.start()
+        if t_start is None:
+            t_start = time.perf_counter()
         if execute_downstream and downstream is None:
             raise ValueError("execute_downstream requires a downstream task")
         x = np.ascontiguousarray(np.asarray(x), dtype=np.float32)
@@ -1276,6 +1280,7 @@ class FleetSupervisor:
         spec = _cost_spec(cost)
         fp = fingerprint or dataset_fingerprint(x)
         with self._lock:
+            self.stats.submit_s += time.perf_counter() - t_start
             if max_backlog is not None and self._backlog_locked() >= max_backlog:
                 self.stats.rejected += 1
                 return None

@@ -34,6 +34,7 @@ from collections import deque
 import numpy as np
 
 from repro.core.types import CostFn, DropConfig
+from repro.obs import span
 from repro.serve_drop.cache import dataset_fingerprint
 from repro.serve_drop.delta import SubscribeQuery, SubscriptionClosed
 from repro.serve_drop.service import DropService, ServeResult
@@ -221,38 +222,45 @@ class IngestFrontend:
         backlog than a light one whose own compute leaves the deadline
         more queue headroom. ``queue_capacity`` remains the hard cap,
         checked atomically with the enqueue (``try_submit``), so
-        concurrent submitters can never jointly overshoot the bound."""
-        if self._closing.is_set() or self._stop.is_set():
-            backlog = self.service.backlog()
-            raise RetryLater(self._retry_after(backlog), backlog)
-        # convert + hash on the submitter's thread: admission needs the
-        # fingerprint for the per-tenant estimate, and try_submit reuses
-        # it (fingerprint=) instead of hashing twice
-        x = np.ascontiguousarray(np.asarray(x), dtype=np.float32)
-        fp = dataset_fingerprint(x)
-        bound = self.max_queue_delay_s
-        if deadline_s is not None:
-            # wait + own_est > deadline ⇒ refuse: the queue may only
-            # consume what the deadline leaves after this tenant's compute
-            budget = max(deadline_s - self._tenant_s(fp), 0.0)
-            bound = budget if bound is None else min(bound, budget)
-        if bound is not None:
-            backlog = self.service.backlog()
-            if self._queue_delay(backlog) > bound:
-                raise RetryLater(self._retry_after(backlog, bound), backlog)
-        qid = self.service.try_submit(
-            x, cfg, cost, method=method, downstream=downstream,
-            execute_downstream=execute_downstream,
-            max_backlog=self.queue_capacity,
-            deadline_s=deadline_s,
-            fingerprint=fp,
-        )
-        if qid is None:
-            backlog = self.service.backlog()
-            raise RetryLater(self._retry_after(backlog), backlog)
-        with self._wake:
-            self._wake.notify_all()
-        return qid
+        concurrent submitters can never jointly overshoot the bound.
+
+        The whole call is one ``drop.submit`` span, booked in the
+        service's ``stats.submit_s``."""
+        t_start = time.perf_counter()
+        with span("drop.submit", rows=len(x)) as sp:
+            if self._closing.is_set() or self._stop.is_set():
+                backlog = self.service.backlog()
+                raise RetryLater(self._retry_after(backlog), backlog)
+            # convert + hash on the submitter's thread: admission needs the
+            # fingerprint for the per-tenant estimate, and try_submit reuses
+            # it (fingerprint=) instead of hashing twice
+            x = np.ascontiguousarray(np.asarray(x), dtype=np.float32)
+            fp = dataset_fingerprint(x)
+            bound = self.max_queue_delay_s
+            if deadline_s is not None:
+                # wait + own_est > deadline ⇒ refuse: the queue may only
+                # consume what the deadline leaves after this tenant's compute
+                budget = max(deadline_s - self._tenant_s(fp), 0.0)
+                bound = budget if bound is None else min(bound, budget)
+            if bound is not None:
+                backlog = self.service.backlog()
+                if self._queue_delay(backlog) > bound:
+                    raise RetryLater(self._retry_after(backlog, bound), backlog)
+            qid = self.service.try_submit(
+                x, cfg, cost, method=method, downstream=downstream,
+                execute_downstream=execute_downstream,
+                max_backlog=self.queue_capacity,
+                deadline_s=deadline_s,
+                fingerprint=fp,
+                t_start=t_start,
+            )
+            if qid is None:
+                backlog = self.service.backlog()
+                raise RetryLater(self._retry_after(backlog), backlog)
+            sp.set_metadata(qid=qid)
+            with self._wake:
+                self._wake.notify_all()
+            return qid
 
     def _per_query_s(self) -> float:
         if self._recent_walls:

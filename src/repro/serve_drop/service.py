@@ -51,7 +51,7 @@ import threading
 import time
 from collections import OrderedDict, deque
 from dataclasses import dataclass, field
-from typing import Callable
+from typing import Callable, ClassVar
 
 import jax.numpy as jnp
 import numpy as np
@@ -65,6 +65,7 @@ from repro.core.subspace import (
 )
 from repro.core.tlb import TLBEstimator
 from repro.core.types import CostFn, DropConfig, ReduceResult
+from repro.obs import span
 from repro.serve_drop.cache import (
     BasisCacheEntry,
     BasisReuseCache,
@@ -102,6 +103,8 @@ class ReduceQuery:
     # effort — entries cached after submit() are not probed
     prefix_fps: dict = field(default_factory=dict)
     t0: float | None = None  # pinned at first dequeue (includes deferral time)
+    # built under the lock at its append to the queue: the enqueue time
+    t_enq: float = field(default_factory=time.perf_counter)
     # absolute expiry (perf_counter seconds), stamped at submit from the
     # caller's deadline_s budget; None = no deadline. Checked at dequeue
     # and between runner steps: an expired query finishes with
@@ -185,6 +188,15 @@ class ServiceStats:
     # per-device occupancy: device label -> iterations stepped there; the
     # single-host service books everything under "default"
     device_iterations: dict = field(default_factory=dict)
+    # stage times, host seconds summed over queries (the ``drop.*`` spans
+    # of ``repro.obs`` mark the same stages in a profiler trace)
+    submit_s: float = 0.0  # submit: conversion, hashing, checks, enqueue
+    queue_wait_s: float = 0.0  # enqueue to routing by _admit, deferrals in
+    work_wait_s: float = 0.0  # a work item's wait in its deque for _pop_work
+    validate_s: float = 0.0  # cache-hit revalidations (_validate)
+    tlb_rounds: int = 0  # TLB device calls (CI doublings) of revalidations
+    transform_s: float = 0.0  # host transform of served analytics
+    downstream_s: float = 0.0  # served analytics items (ServeResult.downstream_s)
 
     def as_dict(self) -> dict:
         return dict(self.__dict__)
@@ -198,6 +210,10 @@ class _InFlight:
     warm_started: bool
     t0: float  # queue-pinned at first dequeue (includes deferral time)
     device: object = None  # mesh device the runner is placed on (sharded)
+    # work items are built under the lock as they enter their deque; a
+    # requeued runner is stamped again
+    t_ready: float = field(default_factory=time.perf_counter)
+    span_name: ClassVar[str] = "drop.item.fit_step"
 
 
 @dataclass(eq=False)
@@ -212,6 +228,8 @@ class _Validation:
     t0: float
     device: object = None  # mesh device to validate on (sharded)
     prefix: bool = False  # entry matched via prefix fingerprint (append)
+    t_ready: float = field(default_factory=time.perf_counter)
+    span_name: ClassVar[str] = "drop.item.validate"
 
 
 @dataclass(eq=False)
@@ -228,6 +246,8 @@ class _SuffixUpdate:
     fingerprint: str
     t0: float
     device: object = None  # mesh device to update on (sharded)
+    t_ready: float = field(default_factory=time.perf_counter)
+    span_name: ClassVar[str] = "drop.item.suffix_update"
 
 
 @dataclass(eq=False)
@@ -248,6 +268,8 @@ class _DeltaServe:
     suffixes: list = field(default_factory=list)  # append: queued suffix rows
     base: ServeResult | None = None  # bootstrap: the finished reduction
     device: object = None  # mesh device to compute on (sharded)
+    t_ready: float = field(default_factory=time.perf_counter)
+    span_name: ClassVar[str] = "drop.item.delta"
 
     @property
     def fingerprint(self) -> str:
@@ -270,6 +292,9 @@ class _Downstream:
     base: ServeResult
     t0: float
     device: object = None  # mesh device to run the analytics on (sharded)
+    t_ready: float = field(default_factory=time.perf_counter)
+    transform_s: float = 0.0  # host transform, set by _apply_downstream
+    span_name: ClassVar[str] = "drop.item.downstream"
 
     @property
     def fingerprint(self) -> str:  # dedup visibility, like the other items
@@ -508,11 +533,18 @@ class DropService:
         max_backlog: int | None = None,
         deadline_s: float | None = None,
         fingerprint: str | None = None,
+        t_start: float | None = None,
     ) -> int | None:
         """Enqueue unless the backlog is at ``max_backlog``; returns the
         query id or None on rejection. The bound check and the append are
         one critical section, so concurrent submitters cannot jointly
         overshoot the bound (ingest backpressure relies on this).
+
+        The submit is one ``drop.submit`` span and is booked in
+        ``stats.submit_s``. A caller that began the submit earlier (the
+        ingest front end, which converts and hashes first inside its own
+        span) passes its ``perf_counter`` start as ``t_start``: the service
+        then books from it and opens no second span.
 
         The O(m*d) float32/contiguity conversion AND all fingerprint hashing
         (full + candidate prefixes) happen HERE, on the submitter's thread
@@ -525,6 +557,17 @@ class DropService:
         A dataset carrying NaN/Inf rows never reaches a runner, the cache,
         or a shared tracker: it finishes immediately with
         ``ServeResult.error="invalid_input"``."""
+        if t_start is None:
+            with span("drop.submit", rows=len(x)) as sp:
+                qid = self.try_submit(
+                    x, cfg, cost, method=method, downstream=downstream,
+                    execute_downstream=execute_downstream,
+                    max_backlog=max_backlog, deadline_s=deadline_s,
+                    fingerprint=fingerprint, t_start=time.perf_counter(),
+                )
+                if qid is not None:
+                    sp.set_metadata(qid=qid)
+            return qid
         x = np.ascontiguousarray(np.asarray(x), dtype=np.float32)
         if not np.all(np.isfinite(x)):
             return self._reject_invalid(x, method)
@@ -544,6 +587,7 @@ class DropService:
                 )
             prefix_fps = {r: dataset_fingerprint(x[:r]) for r in counts}
         with self._lock:
+            self.stats.submit_s += time.perf_counter() - t_start
             if (
                 max_backlog is not None
                 and len(self._queue) + self._inflight_count() >= max_backlog
@@ -803,6 +847,7 @@ class DropService:
         )
         with self._lock:
             self.stats.validation_pairs += e.pairs_used
+            self.stats.tlb_rounds += e.rounds
         if e.mean < q.cfg.target_tlb:
             return False, None  # stale (near-repeat drifted): fall to cold
         # runtime_s stays compute-only (matching the cold path's semantics);
@@ -834,61 +879,70 @@ class DropService:
         deferred: deque[ReduceQuery] = deque()
         while self._queue and self._inflight_count() < self.max_inflight:
             q = self._queue.popleft()
-            if q.t0 is None:
-                q.t0 = time.perf_counter()
-            t0, fp = q.t0, q.fingerprint
-            if q.deadline_t is not None and time.perf_counter() > q.deadline_t:
-                self._expire(q, t0)  # dead on dequeue: no compute for it
-                continue
-            use_cache = self.enable_cache and method_cacheable(q.method)
-            if q.query_id in self._refresh_qids:
-                # background refresh: always a fresh fit — the exact-hit
-                # and degraded paths would re-serve the very staleness
-                # this query exists to clear. Dedup still applies so a
-                # concurrent real fit isn't duplicated.
-                if use_cache and self._fingerprint_inflight(fp, q.method):
-                    deferred.append(q)
-                    continue
-                self.cache.tick()
-                self._launch_cold(q, fp, t0)
-                continue
-            if (
-                use_cache
-                and self.degrade_watermark_s is not None
-                and self._queue_delay_locked() > self.degrade_watermark_s
-            ):
-                # under pressure: ANY cached basis for this fingerprint —
-                # expired TTL, prefix-only match, or a satisfying coarser
-                # method — serves immediately (stale, no revalidation)
-                # rather than queueing fresh compute behind the backlog
-                entry = self.cache.find_degraded(
-                    fp, q.prefix_fps, q.cfg.target_tlb, q.method
-                )
-                if entry is not None:
-                    self._serve_degraded(q, entry, t0)
-                    continue
-            if use_cache and self._fingerprint_inflight(fp, q.method):
+            now = time.perf_counter()
+            if self._route(q, now):
+                self.stats.queue_wait_s += now - q.t_enq
+            else:
                 deferred.append(q)
-                continue
-            self.cache.tick()
-            if use_cache:
-                entry = self.cache.get_exact(fp, q.cfg.target_tlb, q.method)
-                prefix = False
-                if entry is None:
-                    # append-only stream: a cached map fitted on a prefix of
-                    # this dataset (hashed at submit time) is revalidated on
-                    # the grown data instead of refitting cold
-                    entry = self.cache.find_prefix(
-                        q.prefix_fps, q.cfg.target_tlb, q.method
-                    )
-                    prefix = entry is not None
-                if entry is not None:
-                    val = self._route_hit(q, entry, fp, t0, prefix)
-                    self._place_validation(val)  # sharded: pick a device
-                    self._validations.append(val)
-                    continue
-            self._launch_cold(q, fp, t0)
         self._queue.extendleft(reversed(deferred))  # keep submission order
+
+    def _route(self, q: ReduceQuery, now: float) -> bool:
+        """Route one dequeued query (``now``: its dequeue time): expire it,
+        serve it degraded, queue its cache-hit work item or launch it cold.
+        False defers it: its tenant is in flight. Caller holds the lock."""
+        if q.t0 is None:
+            q.t0 = now
+        t0, fp = q.t0, q.fingerprint
+        if q.deadline_t is not None and now > q.deadline_t:
+            self._expire(q, t0)  # dead on dequeue: no compute for it
+            return True
+        use_cache = self.enable_cache and method_cacheable(q.method)
+        if q.query_id in self._refresh_qids:
+            # background refresh: always a fresh fit — the exact-hit
+            # and degraded paths would re-serve the very staleness
+            # this query exists to clear. Dedup still applies so a
+            # concurrent real fit isn't duplicated.
+            if use_cache and self._fingerprint_inflight(fp, q.method):
+                return False
+            self.cache.tick()
+            self._launch_cold(q, fp, t0)
+            return True
+        if (
+            use_cache
+            and self.degrade_watermark_s is not None
+            and self._queue_delay_locked() > self.degrade_watermark_s
+        ):
+            # under pressure: ANY cached basis for this fingerprint —
+            # expired TTL, prefix-only match, or a satisfying coarser
+            # method — serves immediately (stale, no revalidation)
+            # rather than queueing fresh compute behind the backlog
+            entry = self.cache.find_degraded(
+                fp, q.prefix_fps, q.cfg.target_tlb, q.method
+            )
+            if entry is not None:
+                self._serve_degraded(q, entry, t0)
+                return True
+        if use_cache and self._fingerprint_inflight(fp, q.method):
+            return False
+        self.cache.tick()
+        if use_cache:
+            entry = self.cache.get_exact(fp, q.cfg.target_tlb, q.method)
+            prefix = False
+            if entry is None:
+                # append-only stream: a cached map fitted on a prefix of
+                # this dataset (hashed at submit time) is revalidated on
+                # the grown data instead of refitting cold
+                entry = self.cache.find_prefix(
+                    q.prefix_fps, q.cfg.target_tlb, q.method
+                )
+                prefix = entry is not None
+            if entry is not None:
+                val = self._route_hit(q, entry, fp, t0, prefix)
+                self._place_validation(val)  # sharded: pick a device
+                self._validations.append(val)
+                return True
+        self._launch_cold(q, fp, t0)
+        return True
 
     def _route_hit(self, q, entry, fp, t0, prefix):
         """Turn a cache hit into its work item. Normally a revalidation —
@@ -1226,9 +1280,10 @@ class DropService:
         """Next unit of device compute: pending revalidations and suffix
         updates first (they are short and serve a waiting tenant), else a
         runner iteration. Caller holds the lock."""
-        if self._validations:
-            return self._validations.popleft()
-        return self._pop_runner()
+        work = self._validations.popleft() if self._validations else self._pop_runner()
+        if work is not None:
+            self.stats.work_wait_s += time.perf_counter() - work.t_ready
+        return work
 
     def _requeue_runner(self, fl: _InFlight) -> None:
         """Rotate a still-live runner back into flight. Caller holds the lock."""
@@ -1330,6 +1385,7 @@ class DropService:
         bookkeeping; a failed prefix entry still seeds the warm rank
         bound). Verdicts feed the cache's TTL auto-tuner."""
         errored = False
+        tv = time.perf_counter()
         try:
             passed, result = self._validate(val)
         except Exception:
@@ -1337,6 +1393,7 @@ class DropService:
             # NOT a drift observation, so it stays out of the TTL tuner; it
             # is counted, so the cold refit it falls to never hides it
             passed, result, errored = False, None, True
+        validate_s = time.perf_counter() - tv
         q = val.query
         new_tracker = None
         if passed and val.prefix and self._suffix_updatable(q, val.entry):
@@ -1351,6 +1408,7 @@ class DropService:
                 new_tracker = None  # re-register without updater state
         with self._lock:
             self._stepping_now.remove(val)
+            self.stats.validate_s += validate_s
             if errored:
                 self.stats.validation_errors += 1
             else:
@@ -1503,7 +1561,10 @@ class DropService:
         lets the mesh fan-out claim the whole mesh)."""
         from repro.pipeline.optimizer import run_downstream
 
-        xt = ds.base.result.transform(ds.query.x)
+        t = time.perf_counter()
+        with span("drop.transform", qid=ds.query.query_id):
+            xt = ds.base.result.transform(ds.query.x)
+        ds.transform_s = time.perf_counter() - t
         return run_downstream(
             ds.query.downstream,
             xt,
@@ -1533,6 +1594,8 @@ class DropService:
             sr.downstream = out
             sr.downstream_s = downstream_s
             sr.wall_s = time.perf_counter() - ds.t0
+            self.stats.downstream_s += downstream_s
+            self.stats.transform_s += ds.transform_s
             if error is None:
                 self.stats.downstream_runs += 1
             else:
@@ -1898,35 +1961,15 @@ class DropService:
                     more = more or self._work_remains()
             return False, more
         done: list[int] = []
+        if isinstance(work, _DeltaServe):
+            item_id = work.sub.sub_id
+        else:
+            item_id = work.query.query_id
         try:
-            if self._expire_if_due(work):
-                pass  # expired without compute; notification drains below
-            elif isinstance(work, _DeltaServe):
-                self._run_delta(work, done)
-            elif isinstance(work, _Downstream):
-                self._run_downstream(work, done)
-            elif isinstance(work, _SuffixUpdate):
-                self._run_suffix_update(work, done)
-            elif isinstance(work, _Validation):
-                self._run_validation(work, done)
-            else:
-                try:
-                    alive = self._step(work)  # device compute, off the lock
-                except Exception as exc:
-                    with self._lock:
-                        self._stepping_now.remove(work)
-                        self._fail(work, exc)
-                    done.append(work.query.query_id)
-                    alive = None
-                if alive is not None:
-                    with self._lock:
-                        self._stepping_now.remove(work)
-                        if alive:
-                            self._requeue_runner(work)  # rotate: fair share
-                        else:
-                            self._finish(work)
+            with span(work.span_name, qid=item_id):
+                self._dispatch(work, done)
         except Exception as exc:
-            # containment of last resort: the per-path handlers above catch
+            # containment of last resort: _dispatch's per-path handlers catch
             # COMPUTE errors, but a commit section (cache put, tracker merge
             # bookkeeping, stats) raising would otherwise escape into the
             # drain thread with the work item half-retired — the query then
@@ -1948,6 +1991,35 @@ class DropService:
             with self._lock:
                 more = more or self._work_remains()
         return True, more
+
+    def _dispatch(self, work, done: list[int]) -> None:
+        """Run one popped work item off the lock and commit it."""
+        if self._expire_if_due(work):
+            return  # expired without compute; notification drains later
+        if isinstance(work, _DeltaServe):
+            self._run_delta(work, done)
+        elif isinstance(work, _Downstream):
+            self._run_downstream(work, done)
+        elif isinstance(work, _SuffixUpdate):
+            self._run_suffix_update(work, done)
+        elif isinstance(work, _Validation):
+            self._run_validation(work, done)
+        else:
+            try:
+                alive = self._step(work)  # device compute, off the lock
+            except Exception as exc:
+                with self._lock:
+                    self._stepping_now.remove(work)
+                    self._fail(work, exc)
+                done.append(work.query.query_id)
+                return
+            with self._lock:
+                self._stepping_now.remove(work)
+                if alive:
+                    work.t_ready = time.perf_counter()
+                    self._requeue_runner(work)  # rotate: fair share
+                else:
+                    self._finish(work)
 
     def _abandon(self, work, exc: BaseException, done: list[int]) -> None:
         """Finish ``work``'s query with an error after a scheduler-side

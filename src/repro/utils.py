@@ -23,27 +23,6 @@ class Clock:
         return time.perf_counter() - self._t0
 
 
-def block(x: Any) -> Any:
-    """Block until all arrays in a pytree are ready (for honest timing)."""
-    jax.tree_util.tree_map(
-        lambda a: a.block_until_ready() if hasattr(a, "block_until_ready") else a, x
-    )
-    return x
-
-
-def time_fn(fn, *args, warmup: int = 1, iters: int = 3, **kwargs) -> tuple[float, Any]:
-    """Return (best seconds, last result) of fn(*args, **kwargs), jit-warmed."""
-    out = None
-    for _ in range(max(warmup, 0)):
-        out = block(fn(*args, **kwargs))
-    best = float("inf")
-    for _ in range(max(iters, 1)):
-        t0 = time.perf_counter()
-        out = block(fn(*args, **kwargs))
-        best = min(best, time.perf_counter() - t0)
-    return best, out
-
-
 @dataclass
 class RngStream:
     """Deterministic per-purpose numpy RNG fan-out from a single seed."""
