@@ -64,7 +64,7 @@ from repro.core.subspace import (
     suffix_update as subspace_suffix_update,
 )
 from repro.core.tlb import TLBEstimator
-from repro.core.types import CostFn, DropConfig, ReduceResult
+from repro.core.types import CostFn, DropConfig, ReduceResult, transform_blocks
 from repro.obs import span
 from repro.serve_drop.cache import (
     BasisCacheEntry,
@@ -196,6 +196,7 @@ class ServiceStats:
     validate_s: float = 0.0  # cache-hit revalidations (_validate)
     tlb_rounds: int = 0  # TLB device calls (CI doublings) of revalidations
     transform_s: float = 0.0  # host transform of served analytics
+    transform_blocked: int = 0  # served transforms projected in row blocks
     downstream_s: float = 0.0  # served analytics items (ServeResult.downstream_s)
 
     def as_dict(self) -> dict:
@@ -294,6 +295,7 @@ class _Downstream:
     device: object = None  # mesh device to run the analytics on (sharded)
     t_ready: float = field(default_factory=time.perf_counter)
     transform_s: float = 0.0  # host transform, set by _apply_downstream
+    transform_blocks: int = 0  # its row blocks (0: it did not complete)
     span_name: ClassVar[str] = "drop.item.downstream"
 
     @property
@@ -1561,10 +1563,12 @@ class DropService:
         lets the mesh fan-out claim the whole mesh)."""
         from repro.pipeline.optimizer import run_downstream
 
+        blocks = transform_blocks(ds.query.x.shape)
         t = time.perf_counter()
-        with span("drop.transform", qid=ds.query.query_id):
+        with span("drop.transform", qid=ds.query.query_id, blocks=blocks):
             xt = ds.base.result.transform(ds.query.x)
         ds.transform_s = time.perf_counter() - t
+        ds.transform_blocks = blocks
         return run_downstream(
             ds.query.downstream,
             xt,
@@ -1596,6 +1600,7 @@ class DropService:
             sr.wall_s = time.perf_counter() - ds.t0
             self.stats.downstream_s += downstream_s
             self.stats.transform_s += ds.transform_s
+            self.stats.transform_blocked += int(ds.transform_blocks > 1)
             if error is None:
                 self.stats.downstream_runs += 1
             else:

@@ -9,6 +9,7 @@ import jax
 
 from repro.core import DropConfig
 from repro.core.cost import zero_cost
+from repro.core.types import transform_blocks
 from repro.core.tlb import TLBEstimator
 from repro.data import sinusoid_mixture
 from repro.serve_drop import DropService, IngestFrontend
@@ -131,3 +132,39 @@ def test_spans_land_on_the_host_plane_with_their_query(tmp_path):
     assert all(st["pairs"] > 0 for *_, st in spans["tlb.extend"])
     # the submitting thread and the drain thread are different lines
     assert spans["drop.submit"][0][0] != validate[0]
+
+
+def test_blocked_transforms_are_counted_and_tagged(tmp_path):
+    """A StarLightCurves-sized served query (9236 x 1024) is projected in
+    row blocks: one ``transform_blocked`` and ``blocks`` > 1 on its
+    ``drop.transform`` span; a 1370 x 96 one takes one call and no count."""
+    big = sinusoid_mixture(9236, 1024, rank=4, seed=11)[0]
+    small = sinusoid_mixture(1370, 96, rank=4, seed=12)[0]
+    svc = DropService()
+    st = svc.stats
+
+    def serve(fe, x):
+        qid = fe.submit(x, CFG, zero_cost(), downstream="knn",
+                        execute_downstream=True)
+        res = fe.result(qid, timeout=300)
+        assert res.error is None and res.downstream is not None
+        return qid
+
+    with IngestFrontend(svc) as fe:
+        serve(fe, small)  # cold fits and compiles, untraced
+        assert st.transform_blocked == 0
+        serve(fe, big)
+        assert st.transform_blocked == 1
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            q_big = serve(fe, big)
+            assert st.transform_blocked == 2
+            q_small = serve(fe, small)
+            assert st.transform_blocked == 2
+        finally:
+            jax.profiler.stop_trace()
+    blocks = {stats["qid"]: stats["blocks"]
+              for _, name, _, _, stats in _host_events(tmp_path)
+              if name == "drop.transform"}
+    assert blocks == {q_big: transform_blocks(big.shape), q_small: 1}
+    assert blocks[q_big] > 1
