@@ -4,7 +4,7 @@ Tenants are collections at the configuration's shapes; popularity rank r is
 collection r mod C, seed index r div C, so ranks interleave across shapes.
 Set-up serves every tenant cold on a throwaway service (compiling every
 shape, stalls and all), then, on the service the window uses, every tenant
-again and a warm-up prefix of the trace drawn from its own seed stream (the
+again and a warm-up prefix of the trace drawn from its own fixed stream (the
 repository's two-warm-run convention: DROP stops on wall time, so only a run
 without compile stalls pins the shapes the window sees). The window
 then submits each request at its due time from one thread; a request's
@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import sys
 import time
+from collections import Counter
 
 import numpy as np
 
@@ -23,6 +24,7 @@ from bench import data, reference, traffic as traffic_mod
 
 RESULT_WAIT_S = 60.0  # how long after the window closes answers may come
 CHECK_ROWS = 4096  # kNN rows checked per sampled answer
+SCHEDULE_SEED = 0  # warm-up and window schedules, the same in every run
 
 
 def log(msg: str) -> None:
@@ -88,7 +90,7 @@ def serve_all(fe, xs, cfg, downstream: str, chunk: int = 16) -> list:
     return out
 
 
-def setup(tenants: Tenants, config: dict, traffic: dict, cfg, seed: int):
+def setup(tenants: Tenants, config: dict, traffic: dict, cfg):
     """Warm every shape, then fill the window's service from a warm-up
     prefix of the trace. Returns (service, frontend, stamps)."""
     ds = config["downstream"]
@@ -98,7 +100,7 @@ def setup(tenants: Tenants, config: dict, traffic: dict, cfg, seed: int):
         serve_all(fe0, tenants.x, cfg, ds)  # cold, compile stalls and all
     svc, fe = build_service(config)
     fe.start()
-    rng = data.collection_rng(seed, 2)
+    rng = data.collection_rng(SCHEDULE_SEED, 2)
     n = int(traffic["warmup_requests"])
     order = traffic_mod.tenant_sequence(n, len(tenants), traffic, rng)
     # every tenant once first: each cold fit runs once without compile stalls
@@ -214,13 +216,13 @@ class Session:
     front end, and completion stamps. ``measure`` runs one window."""
 
     def __init__(self, cell: dict, seed: int) -> None:
-        self.config, self.traffic, self.seed = cell["config"], cell["traffic"], seed
+        self.config, self.traffic = cell["config"], cell["traffic"]
         self.tenants = Tenants(self.config, self.traffic, seed)
         self.cfg = drop_config(self.config, seed)
         log(f"tenants: {len(self.tenants)} collections "
             f"{sorted({(s['name'], s['m'], s['d']) for s in self.tenants.specs})}")
         self.svc, self.fe, self.stamps = setup(
-            self.tenants, self.config, self.traffic, self.cfg, seed)
+            self.tenants, self.config, self.traffic, self.cfg)
 
     def measure(self, seconds: float, traffic: dict | None = None,
                 stream: int = 6, on_open=None) -> dict:
@@ -228,7 +230,10 @@ class Session:
         just before it opens. Returns the window with its latencies and the
         service counters it moved."""
         traffic = traffic or self.traffic
-        rng = data.collection_rng(self.seed, stream)
+        # the schedule (due times and tenants) is the same in every run: the
+        # seed draws the rows and the program's seed, not the order, which
+        # sets which queries queue behind which
+        rng = data.collection_rng(SCHEDULE_SEED, stream)
         due = traffic_mod.arrivals(traffic, seconds, rng)
         who = traffic_mod.tenant_sequence(len(due), len(self.tenants), traffic, rng)
         before = self.svc.stats.as_dict()
@@ -288,9 +293,13 @@ def run(cell: dict, seed: int, seconds: float, tracer=None) -> dict:
     log(f"checked {checked['answers']} answers, {checked['maps']} distinct maps; "
         f"standard errors under the guaranteed floor per map {checked['tlb_maps_se']}")
     requests = [
-        {"hit": bool(r.cache_hit), "m": int(sess.tenants.x[t].shape[0]), "k": int(r.result.k)}
+        {"hit": bool(r.cache_hit), "tenant": int(t), "m": int(sess.tenants.x[t].shape[0]),
+         "k": int(r.result.k)}
         for r, t in zip(win["results"], win["who"]) if r is not None and r.error is None
     ]
+    served_k = Counter((q["tenant"], sess.tenants.specs[q["tenant"]]["rank"], q["k"])
+                       for q in requests)
+    log(f"window: answers by (tenant, rank, served k) {dict(sorted(served_k.items()))}")
     return {
         "setup_end": win["t0"],
         "attempted": len(win["due"]),

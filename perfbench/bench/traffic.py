@@ -6,18 +6,20 @@ their times were multiples of a warm serve measured in the same run, so a
 faster program was offered more load. Here a rate is requests per second of
 wall time, fixed in the traffic file.
 
-Every seed gets the same work in another order: the number of requests,
-the multiset of inter-arrival gaps (the exponential distribution's
-quantiles) and the number of requests per popularity rank are fixed by the
-traffic file; the seed only orders them. The order is stratified in blocks
-of ``block_requests`` consecutive requests: each block holds one gap from
-each of that many strata of the sorted gaps, and one request from each
-stratum of the requests sorted by rank, so every block of the window offers
-about the same load and the same mix, and the seed moves work only within
-a block. A fully shuffled order lets the seed decide where the busy periods
-fall, which at four fifths of capacity moves the latency percentiles from
-seed to seed. Runs with different seeds then differ by the data, not by
-how much work they offer or when.
+The number of requests, the multiset of inter-arrival gaps (the
+exponential distribution's quantiles) and the number of requests per
+popularity rank are fixed by the traffic file; the generator given to these
+functions only orders them. The order is stratified in blocks of
+``block_requests`` consecutive requests: each block holds one gap from each
+of that many strata of the sorted gaps, and one request from each stratum
+of the requests sorted by rank, so every block of the window offers about
+the same load and the same mix. A fully shuffled order lets the generator
+decide where the busy periods fall, which moves the latency percentiles.
+Even within blocks the order sets which requests queue behind which, and
+moved the median by some 6% from one order to another on a TPU v5e, so
+the query driver (``bench/serve.py``) draws the order from a fixed stream,
+the same in every run: runs with different seeds then differ by the data
+alone, not by how much work they offer, when, or in what order.
 """
 
 from __future__ import annotations

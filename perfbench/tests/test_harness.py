@@ -63,14 +63,41 @@ def test_every_block_holds_one_item_of_each_stratum(n, block):
 
 def test_data_is_made_from_the_seed():
     spec_ = {"id": 0, "m": 50, "d": 16, "rank": 3}
-    x1 = data.make_collection(spec_, 2**33 + 1, 0)[1]
+    src1, x1 = data.make_collection(spec_, 2**33 + 1, 0)
     assert np.array_equal(x1, data.make_collection(spec_, 2**33 + 1, 0)[1])
     assert not np.array_equal(x1, data.make_collection(spec_, 2**33 + 1, 1)[1])
     # another seed: fresh rows from the same population
     src2, x2 = data.make_collection(spec_, 7, 0)
     assert not np.array_equal(x1, x2)
-    assert np.array_equal(src2.basis, data.make_collection(spec_, 2**33 + 1, 0)[0].basis)
+    assert np.array_equal(src2.basis, src1.basis)
+    assert np.array_equal(src2.class_means, src1.class_means)
     assert x1.dtype == np.float32 and np.allclose(x1.mean(1), 0, atol=1e-5)
+    # rank + 1 class means whose covariance over the classes is isotropic
+    cm = src1.class_means.astype(np.float64)
+    assert cm.shape == (4, 3) and np.allclose(cm.sum(0), 0, atol=1e-5)
+    assert np.allclose(cm.T @ cm / len(cm), np.eye(3), atol=1e-5)
+
+
+UCR = spec.load_json(spec.BENCH_DIR / "configs" / "ucr-serve.json")
+HOT = spec.load_json(spec.BENCH_DIR / "traffic" / "hot.json")
+
+
+@pytest.mark.parametrize("seed", [11, 2718281828])
+@pytest.mark.parametrize("col", UCR["collections"], ids=lambda c: c["name"])
+def test_every_tenant_is_served_at_its_rank(col, seed):
+    """Exact PCA of each tenant at its real m x d, on 4000 fixed pairs:
+    one component short of the rank the map misses the target by a wide
+    margin, and at the rank it clears it, so DROP serves k = rank and its
+    revalidation clears the target on the first round of pairs."""
+    target = UCR["drop"]["target_tlb"]
+    for index in range(HOT["seeds_per_collection"]):
+        x = data.make_collection(col, seed, index)[1]
+        x64 = x.astype(np.float64)
+        v = np.linalg.svd(x64 - x64.mean(0), full_matrices=False)[2].T
+        pairs = reference.sample_pairs(len(x), HOT["check_pairs"], np.random.default_rng(0))
+        short = reference.tlb(x, v[:, :col["rank"] - 1], pairs)[0]
+        full = reference.tlb(x, v[:, :col["rank"]], pairs)[0]
+        assert short <= target - 0.015 and full >= target + 0.01, (index, short, full)
 
 
 def test_references_against_brute_force():
