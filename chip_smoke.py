@@ -23,9 +23,9 @@ in float64 (TLB on a fixed pair sample, brute-force kNN, KDE on a row
 subset), within the tolerances defined below. Wall times include
 compilation and are host-clock times, not device metrics.
 
-``--chips 4`` runs only ``ShardedDropService(devices=4)`` with the mesh
-analytics fan-out against a single-device ``DropService`` in the same
-process, query by query, and checks that every device did work.
+``--chips 4`` runs only ``DropService(devices=4)`` (the sharded scheduler,
+with the mesh analytics fan-out) against a single-device ``DropService`` in
+the same process, query by query, and checks that every device did work.
 
 The last line of standard output is one JSON object, printed only when every
 check passed: ``{"ok": true, "device": {"platform", "kind", "count"}}``. The
@@ -440,8 +440,9 @@ def kernel_phase(datasets, cfg, checks, base: dict, seed=0) -> None:
 
 
 def mesh_phase(datasets, cfg, checks, devices: int = 4) -> None:
-    """Four-device path only: ``ShardedDropService`` with the mesh analytics
-    fan-out, then the same queries on a single-device ``DropService``."""
+    """Four-device path only: ``DropService(devices=4)``, the sharded
+    scheduler with the mesh analytics fan-out, then the same queries on a
+    single-device ``DropService``."""
     import jax
 
     from repro.serve_drop import DropService, IngestFrontend, ShardedDropService
@@ -456,7 +457,11 @@ def mesh_phase(datasets, cfg, checks, devices: int = 4) -> None:
             res = {n: fe.result(q, RESULT_TIMEOUT_S) for n, q in qids.items()}
         return res, time.perf_counter() - t0
 
-    mesh = ShardedDropService(devices=devices, analytics_fanout="mesh")
+    mesh = DropService(devices=devices)
+    checks.require(
+        isinstance(mesh, ShardedDropService) and mesh.analytics_fanout == "mesh",
+        f"mesh: DropService(devices={devices}) built {type(mesh).__name__}",
+    )
     got, wall = serve(mesh)
     occupancy = dict(mesh.stats.device_iterations)
     peaks = {
@@ -486,6 +491,11 @@ def mesh_phase(datasets, cfg, checks, devices: int = 4) -> None:
         checks.require(same, f"mesh: {n} kNN differs")
     for counter in ("failures", "downstream_failures", "validation_errors", "drain_failures"):
         checks.require(getattr(mesh.stats, counter) == 0, f"mesh: stats.{counter}")
+    scans, shards = mesh.stats.mesh_scans, mesh.stats.mesh_shards
+    checks.require(
+        scans == len(datasets) and shards == devices * scans,
+        f"mesh: {scans} mesh scans over {shards} shards for {len(datasets)} queries",
+    )
 
 
 # ------------------------------------------------------------------- main

@@ -48,6 +48,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.bucketing import DEFAULT_BUCKETS, ShapeBucketCache, round_up
+from repro.obs import span
 from repro.analytics.pairwise import (
     DEFAULT_BLOCK,
     _clamp_block,
@@ -65,6 +66,7 @@ __all__ = [
     "merge_knn_partials",
     "merge_dbscan_partials",
     "merge_kde_partials",
+    "mesh_shards",
 ]
 
 
@@ -202,7 +204,9 @@ def _mesh_fn(
     Every device runs ``_scan_core`` on its (query shard, dataset shard)
     pair with global offsets from its mesh coordinates; outputs reassemble
     so the host sees d_shards partials in shard order — the same contract
-    the single-device split produces, merged by the same primitives."""
+    the single-device split produces, merged by the same primitives. The
+    jitted program is named per task (``jit__mesh_knn``, ``jit__mesh_dbscan``,
+    ``jit__mesh_kde``), so a device trace tells the fan-outs apart."""
     mesh = Mesh(
         np.asarray(devices, dtype=object).reshape(q_shards, d_shards),
         ("q", "d"),
@@ -240,7 +244,16 @@ def _mesh_fn(
             check_vma=False,
         )(xq_pad, x_pad, m, scalar)
 
+    call.__name__ = call.__qualname__ = f"_mesh_{task}"
     return jax.jit(call)
+
+
+def _mesh_gather(fn, xq_pad, x_pad, m: int, scalar, shards: int):
+    """Dispatch a mesh fan-out and gather its partials to the host."""
+    with span("analytics.mesh_scan", rows=m, shards=shards):
+        return jax.device_get(
+            fn(xq_pad, x_pad, jnp.int32(m), jnp.float32(scalar))
+        )
 
 
 def _mesh_prepare(
@@ -275,6 +288,13 @@ def _resolve_fanout(fanout: str, devices) -> tuple[str, list]:
         if len(devs) > 1:
             return "mesh", devs
     return "xla", []
+
+
+def mesh_shards(fanout: str, devices=None) -> int:
+    """Dataset shards of each scan that ``fanout`` over ``devices`` runs on
+    the mesh (the default 1 x n mesh); 0 where it resolves to one device."""
+    fanout, devs = _resolve_fanout(fanout, devices)
+    return len(devs) if fanout == "mesh" else 0
 
 
 def _mesh_shape(mesh_shape, n: int) -> tuple[int, int]:
@@ -323,9 +343,7 @@ def split_pairwise_knn(
         fn = _mesh_fn(
             tuple(devs), q_shards, d_shards, "knn", bq, bk, bool(use_top_k)
         )
-        idx_p, d2_p = jax.device_get(
-            fn(xq_pad, x_pad, jnp.int32(m), jnp.float32(0.0))
-        )
+        idx_p, d2_p = _mesh_gather(fn, xq_pad, x_pad, m, 0.0, d_shards)
     elif use_kernels and _kernel_backend_live():
         from repro.kernels.pairwise_reduce.ops import pairwise_knn_split_reduce
 
@@ -351,7 +369,9 @@ def split_pairwise_knn(
                 shards=shards,
             )
         )
-    idx, d2 = merge_knn_partials(np.asarray(idx_p), np.asarray(d2_p))
+    idx_p, d2_p = np.asarray(idx_p), np.asarray(d2_p)
+    with span("analytics.merge", rows=m, shards=idx_p.shape[0]):
+        idx, d2 = merge_knn_partials(idx_p, d2_p)
     return idx[:m], d2[:m]
 
 
@@ -384,9 +404,7 @@ def split_pairwise_dbscan(
             x, None, q_shards, d_shards, bq, bk, bucket
         )
         fn = _mesh_fn(tuple(devs), q_shards, d_shards, "dbscan", bq, bk, False)
-        counts_p, packed = jax.device_get(
-            fn(xq_pad, x_pad, jnp.int32(m), jnp.float32(eps2))
-        )
+        counts_p, packed = _mesh_gather(fn, xq_pad, x_pad, m, eps2, d_shards)
         # the mesh path reassembles the packed words globally already —
         # only the counts still carry a shard axis to fold
         counts = (
@@ -470,9 +488,7 @@ def split_pairwise_kde(
             x, queries, q_shards, d_shards, bq, bk, bucket
         )
         fn = _mesh_fn(tuple(devs), q_shards, d_shards, "kde", bq, bk, False)
-        sums_p, comps_p = jax.device_get(
-            fn(xq_pad, x_pad, jnp.int32(m), jnp.float32(inv))
-        )
+        sums_p, comps_p = _mesh_gather(fn, xq_pad, x_pad, m, inv, d_shards)
     elif use_kernels and _kernel_backend_live():
         from repro.kernels.pairwise_reduce.ops import pairwise_kde_split_reduce
 

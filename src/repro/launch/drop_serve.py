@@ -360,8 +360,12 @@ def main() -> None:
         print(f"fleet of {args.fleet} worker processes "
               f"({args.placement} placement): {svc.devices}")
         cost = None
-    elif args.devices > 1:
-        svc = ShardedDropService(
+    else:
+        # --devices > 1 builds the sharded scheduler, whose analytics
+        # default to the mesh fan-out; one device, the plain service
+        fanout = {} if args.analytics_fanout is None else {
+            "analytics_fanout": args.analytics_fanout}
+        svc = DropService(
             devices=args.devices,
             max_inflight=args.max_inflight,
             cache_entries=args.cache_entries,
@@ -370,26 +374,14 @@ def main() -> None:
             enable_suffix_update=not args.no_suffix_update,
             suffix_budget=args.suffix_budget,
             analytics_split=args.analytics_split,
-            analytics_fanout=args.analytics_fanout,
             degrade_watermark_s=args.degrade_watermark_s,
             state_dir=args.state_dir,
+            **fanout,
         )
-        print(f"sharded scheduler over {len(svc.devices)} devices: "
-              f"{[str(d) for d in svc.devices]} "
-              f"(analytics fanout: {svc.analytics_fanout})")
-    else:
-        svc = DropService(
-            max_inflight=args.max_inflight,
-            cache_entries=args.cache_entries,
-            enable_cache=not args.no_cache,
-            cache_ttl=args.cache_ttl,
-            enable_suffix_update=not args.no_suffix_update,
-            suffix_budget=args.suffix_budget,
-            analytics_split=args.analytics_split,
-            analytics_fanout=args.analytics_fanout or "xla",
-            degrade_watermark_s=args.degrade_watermark_s,
-            state_dir=args.state_dir,
-        )
+        if isinstance(svc, ShardedDropService):
+            print(f"sharded scheduler over {len(svc.devices)} devices: "
+                  f"{[str(d) for d in svc.devices]} "
+                  f"(analytics fanout: {svc.analytics_fanout})")
         if args.state_dir and (
             svc.stats.recovery_snapshots or svc.stats.recovery_subs
         ):

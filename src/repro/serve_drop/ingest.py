@@ -58,7 +58,7 @@ class IngestFrontend:
 
     Usage::
 
-        with IngestFrontend(ShardedDropService(devices=4)) as fe:
+        with IngestFrontend(DropService(devices=4)) as fe:
             qid = fe.submit(x, cfg)          # may raise RetryLater
             res = fe.result(qid, timeout=30)
     """

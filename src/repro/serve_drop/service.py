@@ -41,8 +41,9 @@ behind device compute. The ``on_result`` hook fires with no scheduler lock
 held (waiters may re-enter ``take_result`` freely). A runner iteration that
 raises is contained: the query finishes with ``ServeResult.error`` set and
 the scheduler keeps draining the rest. ``ShardedDropService`` builds on
-this by running one drain thread per mesh device, and ``serve_drop.ingest``
-layers the bounded-queue async front-end on top.
+this by running one drain thread per mesh device (``DropService(devices=n)``
+builds it where n resolves to more than one device), and
+``serve_drop.ingest`` layers the bounded-queue async front-end on top.
 """
 
 from __future__ import annotations
@@ -56,6 +57,7 @@ from typing import Callable, ClassVar
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analytics.split import mesh_shards
 from repro.core.bucketing import DEFAULT_BUCKETS, ShapeBucketCache
 from repro.core.reducer import Reducer, make_reducer, method_cacheable
 from repro.core.subspace import (
@@ -198,6 +200,8 @@ class ServiceStats:
     transform_s: float = 0.0  # host transform of served analytics
     transform_blocked: int = 0  # served transforms projected in row blocks
     downstream_s: float = 0.0  # served analytics items (ServeResult.downstream_s)
+    mesh_scans: int = 0  # served analytics scans fanned out over the mesh
+    mesh_shards: int = 0  # their dataset shards, summed over those scans
 
     def as_dict(self) -> dict:
         return dict(self.__dict__)
@@ -304,11 +308,29 @@ class _Downstream:
 
 
 class DropService:
-    """Multi-tenant DROP scheduler with an LRU basis-reuse cache."""
+    """Multi-tenant DROP scheduler with an LRU basis-reuse cache.
+
+    ``devices`` is how many devices the service runs on (or a list of
+    them). Where it resolves to more than one visible device,
+    ``DropService(devices=n)`` builds the ``ShardedDropService`` over them:
+    placement, one drain thread per device, and the served analytics
+    fanned across the devices by dataset shard. None, or one device, builds
+    this single-device service."""
+
+    def __new__(cls, *args, devices: int | list | None = None, **kwargs):
+        if cls is DropService and devices is not None:
+            from repro.serve_drop.sharded import ShardedDropService
+            from repro.sharding.specs import serve_devices
+
+            n = len(serve_devices(devices) if isinstance(devices, int) else devices)
+            if n > 1:
+                return super().__new__(ShardedDropService)
+        return super().__new__(cls)
 
     def __init__(
         self,
         *,
+        devices: int | list | None = None,  # one here: resolved by __new__
         max_inflight: int = 4,
         cache_entries: int = 16,
         bucket: ShapeBucketCache | None = None,
@@ -344,6 +366,9 @@ class DropService:
         self.analytics_devices = (
             None if analytics_devices is None else tuple(analytics_devices)
         )
+        # dataset shards of each served scan where the fan-out resolves to
+        # the mesh (booked as mesh_scans / mesh_shards); 0 on one device
+        self._mesh_shards = mesh_shards(analytics_fanout, self.analytics_devices)
         # append-only escalation knobs: a prefix-matched suffix larger than
         # suffix_budget * fitted rows skips revalidation (a map fitted that
         # many rows ago mostly buys a failed validation) and goes straight
@@ -1603,6 +1628,9 @@ class DropService:
             self.stats.transform_blocked += int(ds.transform_blocks > 1)
             if error is None:
                 self.stats.downstream_runs += 1
+                if self._mesh_shards:
+                    self.stats.mesh_scans += 1
+                    self.stats.mesh_shards += self._mesh_shards
             else:
                 sr.error = error
                 self.stats.downstream_failures += 1

@@ -33,6 +33,8 @@ execute identical programs.
 
 With one visible device the scheduler degenerates exactly to the base
 class (single slot, no threads), so CPU test environments run unchanged.
+``DropService(devices=n)`` builds this scheduler where ``n`` resolves to
+more than one visible device, and the base service otherwise.
 """
 
 from __future__ import annotations
