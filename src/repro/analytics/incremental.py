@@ -57,7 +57,6 @@ from repro.analytics.pairwise import (
     DEFAULT_BLOCK,
     NeighborDecoder,
     _clamp_block,
-    _default_top_k,
     _pad_rows,
     _scan_core,
     pairwise_dbscan,
@@ -69,13 +68,12 @@ from repro.core.bucketing import DEFAULT_BUCKETS, ShapeBucketCache
 __all__ = ["IncrementalAnalytics", "AnalyticsSnapshot"]
 
 
-@partial(jax.jit, static_argnames=("task", "bq", "bk", "use_top_k"))
-def _rect_scan(xq, x, m, scalar, col_offset, row_offset, task, bq, bk, use_top_k):
+@partial(jax.jit, static_argnames=("task", "bq", "bk"))
+def _rect_scan(xq, x, m, scalar, col_offset, row_offset, task, bq, bk):
     """One rectangular (query shard x dataset shard) pass of the fused tile
     body — the sequential engine with nonzero global offsets."""
     return _scan_core(
-        xq, x, m, scalar, col_offset, row_offset,
-        task=task, bq=bq, bk=bk, use_top_k=use_top_k,
+        xq, x, m, scalar, col_offset, row_offset, task=task, bq=bq, bk=bk
     )
 
 
@@ -88,7 +86,6 @@ def _run_rect(
     row_offset: int,
     task: str,
     block: int,
-    use_top_k: bool,
     bucket: ShapeBucketCache,
 ):
     """Host wrapper: pad both shards through the shared buckets, run the
@@ -105,7 +102,7 @@ def _run_rect(
             jnp.asarray(xq_pad), jnp.asarray(xk_pad),
             jnp.int32(m_total), jnp.float32(scalar),
             jnp.int32(col_offset), jnp.int32(row_offset),
-            task=task, bq=bq, bk=bk, use_top_k=use_top_k,
+            task=task, bq=bq, bk=bk,
         )
     )
     return np.asarray(a)[:nq], np.asarray(b)[:nq]
@@ -298,7 +295,6 @@ class IncrementalAnalytics:
         min_samples: int = 5,
         bandwidth: float = 1.0,
         block: int = DEFAULT_BLOCK,
-        use_top_k: bool | None = None,
         bucket: ShapeBucketCache | None = None,
     ) -> None:
         self.eps = float(eps)
@@ -306,7 +302,6 @@ class IncrementalAnalytics:
         self.bandwidth = float(bandwidth)
         self.block = int(block)
         self.bucket = bucket or DEFAULT_BUCKETS
-        self._use_top_k = use_top_k
         self.rebuild(y)
 
     # float32(eps * eps): ONE rounding, matching pairwise_dbscan exactly —
@@ -323,9 +318,6 @@ class IncrementalAnalytics:
     def rows(self) -> int:
         return int(self._y.shape[0])
 
-    def _top_k(self, m: int) -> bool:
-        return _default_top_k(m) if self._use_top_k is None else self._use_top_k
-
     # ----------------------------------------------------------- rebuild
 
     def rebuild(self, y: np.ndarray) -> AnalyticsSnapshot:
@@ -337,8 +329,7 @@ class IncrementalAnalytics:
         self._y = y
         m = y.shape[0]
         self.nn_idx, self.nn_d2 = pairwise_knn(
-            y, self.block, self.block,
-            use_top_k=self._use_top_k, bucket=self.bucket,
+            y, self.block, self.block, bucket=self.bucket
         )
         counts, packed = pairwise_dbscan(
             y, self.eps, self.block, self.block, bucket=self.bucket
@@ -352,7 +343,7 @@ class IncrementalAnalytics:
         # KDE: keep the compensated pairs' exact float64 value per row; the
         # density divides by the CURRENT row count at snapshot time
         scan = _run_rect(
-            y, y, m, self._inv2h2, 0, 0, "kde", self.block, False, self.bucket
+            y, y, m, self._inv2h2, 0, 0, "kde", self.block, self.bucket
         )
         self._kde64 = scan[0].astype(np.float64) + scan[1].astype(np.float64)
         return self.snapshot()
@@ -378,7 +369,6 @@ class IncrementalAnalytics:
             )
         grown = np.concatenate([self._y, y_new], axis=0)
         m = m_old + s
-        top_k = self._top_k(m)
 
         # kNN: scan A folds new columns into the old carry (strict <: the
         # old state, holding lower column indices, keeps ties — the cold
@@ -386,11 +376,11 @@ class IncrementalAnalytics:
         # reduction in the cold scan's own tile layout
         idx_a, d2_a = _run_rect(
             self._y, y_new, m, 0.0, m_old, 0,
-            "knn", self.block, top_k, self.bucket,
+            "knn", self.block, self.bucket,
         )
         idx_b, d2_b = _run_rect(
             y_new, grown, m, 0.0, 0, m_old,
-            "knn", self.block, top_k, self.bucket,
+            "knn", self.block, self.bucket,
         )
         better = d2_a < self.nn_d2
         changed = np.flatnonzero(better)
@@ -403,11 +393,11 @@ class IncrementalAnalytics:
         # label repair confined to appended/promoted neighborhoods
         cnt_a, packed_a = _run_rect(
             self._y, y_new, m, self._eps2, m_old, 0,
-            "dbscan", self.block, False, self.bucket,
+            "dbscan", self.block, self.bucket,
         )
         cnt_b, packed_b = _run_rect(
             y_new, grown, m, self._eps2, 0, m_old,
-            "dbscan", self.block, False, self.bucket,
+            "dbscan", self.block, self.bucket,
         )
         prev_degrees = self.degrees
         self.degrees = np.concatenate(
@@ -421,11 +411,11 @@ class IncrementalAnalytics:
         # KDE: compensated pairs folded in float64 (shard-merge semantics)
         sum_a, comp_a = _run_rect(
             self._y, y_new, m, self._inv2h2, m_old, 0,
-            "kde", self.block, False, self.bucket,
+            "kde", self.block, self.bucket,
         )
         sum_b, comp_b = _run_rect(
             y_new, grown, m, self._inv2h2, 0, m_old,
-            "kde", self.block, False, self.bucket,
+            "kde", self.block, self.bucket,
         )
         self._kde64 = np.concatenate([
             self._kde64 + (sum_a.astype(np.float64) + comp_a.astype(np.float64)),

@@ -33,14 +33,16 @@ Invariants (see ``analytics/README.md``):
   and the true row count ``m`` is a traced scalar (datasets landing in the
   same bucket share one executable).
 
-Backend gating (measured, see ``knn._use_top_k``): the per-tile kNN
-reduction uses ``lax.top_k(2)`` only off-CPU — on XLA:CPU ``top_k`` is a
-20-40x pessimization at these shapes while where+argmin fuses into a single
-pass.  ``use_kernels=True`` routes the scan through the
-``kernels/pairwise_reduce`` Pallas kernel where a kernel backend is live
-(TPU native, or interpret mode under ``REPRO_PALLAS_INTERPRET=1``); on a
-plain CPU backend it falls back to this fused jnp scan, which IS the
-optimized CPU path — the flag is always safe to set.
+The per-tile kNN reduction is a masked min / first-occurrence argmin on
+every backend. A ``top_k(2)`` reduction was measured 20-40x slower on
+XLA:CPU and, on a TPU v5e, 12-18x slower per 1024x1024 tile than the
+masked form (``PERF.md``, section 6): the TPU has no sort unit, so
+``top_k`` lowers to a sort network over the tile.  ``use_kernels=True``
+routes the scan through the ``kernels/pairwise_reduce`` Pallas kernel
+where a kernel backend is live (TPU native, or interpret mode under
+``REPRO_PALLAS_INTERPRET=1``); on a plain CPU backend it falls back to
+this fused jnp scan, which IS the optimized CPU path — the flag is always
+safe to set.
 """
 
 from __future__ import annotations
@@ -118,26 +120,17 @@ def _tile_d2(xqt, sq_q, x, sq_x, j, bk, m, col_offset):
     return d2, cols
 
 
-def _knn_tile(carry, d2, cols, rows, use_top_k):
+def _knn_tile(carry, d2, cols, rows):
     """Fold one distance tile into the running (min-d2, argmin) carry.
 
-    Strict ``<`` keeps the earlier tile on ties, and both per-tile
-    reductions keep the first occurrence — together that reproduces the
-    global-argmin first-occurrence tie-break of the legacy path exactly."""
+    The query's own column is masked to +inf, then one min and one
+    first-occurrence argmin reduce the tile. Strict ``<`` keeps the earlier
+    tile on ties — together that reproduces the global-argmin
+    first-occurrence tie-break of the legacy path exactly."""
     best_d2, best_idx = carry
-    if use_top_k:
-        # accelerator reduction: one top_k(2) partial-sort pass — if the
-        # query's own row is the top hit the runner-up is the neighbor
-        neg_vals, loc = lax.top_k(-d2, 2)
-        cand = cols[loc]  # (bq, 2) global indices
-        self_first = cand[:, 0] == rows
-        t_d2 = jnp.where(self_first, -neg_vals[:, 1], -neg_vals[:, 0])
-        t_idx = jnp.where(self_first, cand[:, 1], cand[:, 0])
-    else:
-        # CPU reduction: mask+argmin fuses into a single pass over the tile
-        d2 = jnp.where(rows[:, None] == cols[None, :], jnp.inf, d2)
-        t_d2 = jnp.min(d2, axis=1)
-        t_idx = cols[jnp.argmin(d2, axis=1)]
+    d2 = jnp.where(rows[:, None] == cols[None, :], jnp.inf, d2)
+    t_d2 = jnp.min(d2, axis=1)
+    t_idx = cols[jnp.argmin(d2, axis=1)]
     better = t_d2 < best_d2
     return (
         jnp.where(better, t_d2, best_d2),
@@ -170,7 +163,6 @@ def _scan_core(
     task: str,
     bq: int,
     bk: int,
-    use_top_k: bool,
 ):
     """The tiled pairwise scan over one (query shard, dataset shard) pair.
 
@@ -201,7 +193,7 @@ def _scan_core(
 
             def k_body(j, carry):
                 d2, cols = _tile_d2(xqt, sq_q, x, sq_x, j, bk, m, col_offset)
-                return _knn_tile(carry, d2, cols, rows, use_top_k)
+                return _knn_tile(carry, d2, cols, rows)
 
             init = (
                 jnp.full((bq,), jnp.inf, jnp.float32),
@@ -290,10 +282,7 @@ def _scan_core(
     return lax.fori_loop(0, mq_pad // bq, q_body, init)
 
 
-@partial(
-    jax.jit,
-    static_argnames=("task", "bq", "bk", "use_top_k"),
-)
+@partial(jax.jit, static_argnames=("task", "bq", "bk"))
 def _fused_scan(
     xq: jax.Array,
     x: jax.Array,
@@ -302,16 +291,12 @@ def _fused_scan(
     task: str,
     bq: int,
     bk: int,
-    use_top_k: bool,
 ):
     """The whole SEQUENTIAL pairwise scan as one device computation (the
     split/mesh variants live in ``analytics.split``; output contract is
     ``_scan_core``'s with both offsets zero)."""
     zero = jnp.int32(0)
-    return _scan_core(
-        xq, x, m, scalar, zero, zero,
-        task=task, bq=bq, bk=bk, use_top_k=use_top_k,
-    )
+    return _scan_core(xq, x, m, scalar, zero, zero, task=task, bq=bq, bk=bk)
 
 
 def _clamp_block(block: int, rows: int, word: int = 64) -> int:
@@ -357,30 +342,17 @@ def _prepare(
     return x, q, xq_pad, xk_pad
 
 
-def _default_top_k(m: int) -> bool:
-    from repro.analytics.knn import _use_top_k
-
-    return _use_top_k() and m >= 2
-
-
 def pairwise_knn(
     x: np.ndarray,
     block_q: int = DEFAULT_BLOCK,
     block_k: int = DEFAULT_BLOCK,
     *,
     use_kernels: bool = False,
-    use_top_k: bool | None = None,
     bucket: ShapeBucketCache | None = None,
 ) -> tuple[np.ndarray, np.ndarray]:
-    """Nearest OTHER row per row of ``x``: (indices int32, squared dists).
-
-    ``use_top_k=None`` picks the measured per-backend reduction (top_k(2)
-    off-CPU, mask+argmin on CPU); tests pass an explicit bool to exercise
-    both on one backend."""
+    """Nearest OTHER row per row of ``x``: (indices int32, squared dists)."""
     bucket = bucket or DEFAULT_BUCKETS
     m = x.shape[0]
-    if use_top_k is None:
-        use_top_k = _default_top_k(m)
     block_q = _clamp_block(block_q, m)
     block_k = _clamp_block(block_k, m)
     x, _q, xq_pad, xk_pad = _prepare(x, None, block_q, block_k, bucket)
@@ -397,7 +369,6 @@ def pairwise_knn(
             task="knn",
             bq=block_q,
             bk=block_k,
-            use_top_k=use_top_k,
         )
     idx, d2 = jax.device_get((idx, d2))  # the single transfer
     return np.asarray(idx)[:m], np.asarray(d2)[:m]
@@ -442,7 +413,6 @@ def pairwise_dbscan(
             task="dbscan",
             bq=block_q,
             bk=block_k,
-            use_top_k=False,
         )
     counts, packed = jax.device_get((counts, packed))
     return np.asarray(counts)[:m], np.asarray(packed)[:m]
@@ -480,7 +450,6 @@ def pairwise_kde(
             task="kde",
             bq=block_q,
             bk=block_k,
-            use_top_k=False,
         )
     sums, comps = jax.device_get((sums, comps))
     return kde_from_compensated(

@@ -52,7 +52,6 @@ from repro.obs import span
 from repro.analytics.pairwise import (
     DEFAULT_BLOCK,
     _clamp_block,
-    _default_top_k,
     _kernel_backend_live,
     _pad_rows,
     _scan_core,
@@ -124,9 +123,7 @@ def merge_kde_partials(
 # ------------------------------------------------------- single-device split
 
 
-@partial(
-    jax.jit, static_argnames=("task", "bq", "bk", "use_top_k", "shards")
-)
+@partial(jax.jit, static_argnames=("task", "bq", "bk", "shards"))
 def _split_scan(
     xq: jax.Array,  # (mq_pad, d) padded queries, shared by every shard
     x_sh: jax.Array,  # (shards, shard_rows, d) tile-aligned dataset shards
@@ -135,7 +132,6 @@ def _split_scan(
     task: str,
     bq: int,
     bk: int,
-    use_top_k: bool,
     shards: int,
 ):
     """All shard partials as ONE batched device computation (vmap over the
@@ -147,8 +143,7 @@ def _split_scan(
 
     def one(xs, off):
         return _scan_core(
-            xq, xs, m, scalar, off, zero,
-            task=task, bq=bq, bk=bk, use_top_k=use_top_k,
+            xq, xs, m, scalar, off, zero, task=task, bq=bq, bk=bk
         )
 
     return jax.vmap(one)(x_sh, offsets)
@@ -197,7 +192,6 @@ def _mesh_fn(
     task: str,
     bq: int,
     bk: int,
-    use_top_k: bool,
 ):
     """Compiled shard_map fan-out over a (q_shards, d_shards) device mesh.
 
@@ -220,8 +214,7 @@ def _mesh_fn(
             row0 = (lax.axis_index("q") * lq).astype(jnp.int32)
             col0 = (lax.axis_index("d") * lk).astype(jnp.int32)
             outs = _scan_core(
-                xq_l, x_l, m_l, scalar_l, col0, row0,
-                task=task, bq=bq, bk=bk, use_top_k=use_top_k,
+                xq_l, x_l, m_l, scalar_l, col0, row0, task=task, bq=bq, bk=bk
             )
             if task == "dbscan":
                 counts, packed = outs
@@ -318,7 +311,6 @@ def split_pairwise_knn(
     block_k: int = DEFAULT_BLOCK,
     *,
     use_kernels: bool = False,
-    use_top_k: bool | None = None,
     fanout: str = "xla",
     devices=None,
     mesh_shape: tuple[int, int] | None = None,
@@ -329,8 +321,6 @@ def split_pairwise_knn(
     bucket = bucket or DEFAULT_BUCKETS
     m = x.shape[0]
     shards = max(1, int(shards))
-    if use_top_k is None:
-        use_top_k = _default_top_k(m)
     bq = _clamp_block(block_q, m)
     bk = _clamp_block(block_k, m)
 
@@ -340,9 +330,7 @@ def split_pairwise_knn(
         xq_pad, x_pad, _ = _mesh_prepare(
             x, None, q_shards, d_shards, bq, bk, bucket
         )
-        fn = _mesh_fn(
-            tuple(devs), q_shards, d_shards, "knn", bq, bk, bool(use_top_k)
-        )
+        fn = _mesh_fn(tuple(devs), q_shards, d_shards, "knn", bq, bk)
         idx_p, d2_p = _mesh_gather(fn, xq_pad, x_pad, m, 0.0, d_shards)
     elif use_kernels and _kernel_backend_live():
         from repro.kernels.pairwise_reduce.ops import pairwise_knn_split_reduce
@@ -365,7 +353,6 @@ def split_pairwise_knn(
                 task="knn",
                 bq=bq,
                 bk=bk,
-                use_top_k=use_top_k,
                 shards=shards,
             )
         )
@@ -403,7 +390,7 @@ def split_pairwise_dbscan(
         xq_pad, x_pad, mk_pad_seq = _mesh_prepare(
             x, None, q_shards, d_shards, bq, bk, bucket
         )
-        fn = _mesh_fn(tuple(devs), q_shards, d_shards, "dbscan", bq, bk, False)
+        fn = _mesh_fn(tuple(devs), q_shards, d_shards, "dbscan", bq, bk)
         counts_p, packed = _mesh_gather(fn, xq_pad, x_pad, m, eps2, d_shards)
         # the mesh path reassembles the packed words globally already —
         # only the counts still carry a shard axis to fold
@@ -447,7 +434,6 @@ def split_pairwise_dbscan(
                 task="dbscan",
                 bq=bq,
                 bk=bk,
-                use_top_k=False,
                 shards=shards,
             )
         )
@@ -487,7 +473,7 @@ def split_pairwise_kde(
         xq_pad, x_pad, _ = _mesh_prepare(
             x, queries, q_shards, d_shards, bq, bk, bucket
         )
-        fn = _mesh_fn(tuple(devs), q_shards, d_shards, "kde", bq, bk, False)
+        fn = _mesh_fn(tuple(devs), q_shards, d_shards, "kde", bq, bk)
         sums_p, comps_p = _mesh_gather(fn, xq_pad, x_pad, m, inv, d_shards)
     elif use_kernels and _kernel_backend_live():
         from repro.kernels.pairwise_reduce.ops import pairwise_kde_split_reduce
@@ -510,7 +496,6 @@ def split_pairwise_kde(
                 task="kde",
                 bq=bq,
                 bk=bk,
-                use_top_k=False,
                 shards=shards,
             )
         )

@@ -84,31 +84,6 @@ def test_knn_accuracy_on_separable_classes():
     assert knn_retrieval_accuracy(x, y) > 0.8
 
 
-def test_knn_top_k_path_matches_argmin_path():
-    """The accelerator self-exclusion (top_k(2)) must return the same
-    neighbors as the CPU mask+argmin path, including the padded tail block
-    and near-duplicate rows (self may or may not be the top hit)."""
-    import jax.numpy as jnp
-
-    from repro.analytics.knn import _nn_block
-
-    rng = np.random.default_rng(3)
-    x = rng.normal(size=(130, 8)).astype(np.float32)
-    x[7] = x[3] + 1e-4  # near-duplicate pair: top-2 ordering is exercised
-    xj = jnp.asarray(x)
-    block = 64
-    for a in range(0, x.shape[0], block):
-        xq = xj[a : a + block]
-        if xq.shape[0] < block:
-            xq = jnp.pad(xq, ((0, block - xq.shape[0]), (0, 0)))
-        ref, _ = _nn_block(xq, xj, jnp.int32(a), block, False)
-        top, _ = _nn_block(xq, xj, jnp.int32(a), block, True)
-        n = min(block, x.shape[0] - a)
-        np.testing.assert_array_equal(
-            np.asarray(ref)[:n], np.asarray(top)[:n]
-        )
-
-
 def test_dbscan_finds_two_blobs():
     rng = np.random.default_rng(0)
     a = rng.normal(0, 0.1, size=(50, 2))

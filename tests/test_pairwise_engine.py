@@ -3,12 +3,14 @@ tasks (kNN indices, DBSCAN labels, KDE densities) over padded tails,
 near-duplicate rows, and the m=1 degenerate input; both the CPU
 (mask+argmin) and interpret-mode Pallas kernel paths; the packed-bitmask
 neighbor-set property; the legacy DBSCAN remainder-recompile regression;
-and the cost-model extension."""
+the cost-model extension; kNN self-exclusion on every path against a
+float64 brute force; and no sort in any compiled kNN scan."""
+
+import sys
+from functools import partial
 
 import numpy as np
 import pytest
-
-import sys
 
 from repro.analytics import (
     dbscan,
@@ -32,8 +34,7 @@ dbscan_mod = sys.modules["repro.analytics.dbscan"]
 @pytest.fixture(scope="module")
 def xdup():
     """Seeded data with a padded tail (131 % 32 != 0) and a near-duplicate
-    pair (rows 3/7 differ by 1e-4: exercises top-2 ordering and tie-ish
-    argmin behavior)."""
+    pair (rows 3/7 differ by 1e-4: exercises tie-ish argmin behavior)."""
     rng = np.random.default_rng(3)
     x = rng.normal(size=(131, 8)).astype(np.float32)
     x[7] = x[3] + 1e-4
@@ -43,15 +44,134 @@ def xdup():
 # ------------------------------------------------------------------- kNN
 
 
-@pytest.mark.parametrize("use_top_k", [False, True])
-def test_knn_fused_matches_legacy(xdup, use_top_k):
-    """Both engine reductions (CPU mask+argmin, accelerator top_k(2))
-    reproduce the legacy host loop exactly, padded tail included."""
+def test_knn_fused_matches_legacy(xdup):
+    """The engine's masked min/argmin reduction reproduces the legacy host
+    loop exactly, padded tail included."""
     ref = nearest_neighbors_legacy(xdup, block=64)
-    idx, d2 = pairwise_knn(xdup, 32, 32, use_top_k=use_top_k)
+    idx, d2 = pairwise_knn(xdup, 32, 32)
     np.testing.assert_array_equal(idx, ref)
     true_d2 = np.sum((xdup - xdup[idx]) ** 2, axis=1)
     np.testing.assert_allclose(d2, true_d2, rtol=1e-3, atol=1e-4)
+
+
+def _grid_rows(m: int, d: int) -> np.ndarray:
+    """Rows on a quarter grid in [-4, 4]: every squared distance, in the
+    unrolled (d <= 4) and the gemm body alike, is exact in float32, so
+    ties are exact ties and a float64 brute force is an exact oracle.
+
+    At m = 150 with 64-row tiles (pad to 192): exact duplicates straddle
+    the tile boundaries (63/64, 127/128), a triple spans three tiles
+    (5/70/140), a near-duplicate pair (one grid step apart) straddles a
+    boundary (100/129), and the last real row, in the padded tail tile,
+    duplicates row 0."""
+    rng = np.random.default_rng(11)
+    x = (rng.integers(-16, 17, size=(m, d)) / 4.0).astype(np.float32)
+    if m == 150:
+        x[64] = x[63]
+        x[128] = x[127]
+        x[70] = x[140] = x[5]
+        x[129] = x[100]
+        x[129, 0] += 0.25
+        x[149] = x[0]
+    return x
+
+
+def _knn_float64(x: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """Brute-force nearest OTHER row in float64, first occurrence on ties."""
+    x64 = x.astype(np.float64)
+    d2 = ((x64[:, None, :] - x64[None, :, :]) ** 2).sum(axis=-1)
+    np.fill_diagonal(d2, np.inf)
+    idx = np.argmin(d2, axis=1)
+    return idx, d2[np.arange(x.shape[0]), idx]
+
+
+def _knn_incremental(x: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    from repro.analytics.incremental import IncrementalAnalytics
+
+    m0 = max(1, x.shape[0] // 2)
+    inc = IncrementalAnalytics(x[:m0], eps=0.5, block=64)
+    inc.append(x[m0:])
+    snap = inc.snapshot()
+    return snap.knn_idx, snap.knn_d2
+
+
+def _knn_split(x: np.ndarray, shards: int) -> tuple[np.ndarray, np.ndarray]:
+    from repro.analytics.split import split_pairwise_knn
+
+    return split_pairwise_knn(x, shards=shards, block_q=64, block_k=64)
+
+
+_KNN_PATHS = {
+    "sequential": lambda x: pairwise_knn(x, 64, 64),
+    "split2": lambda x: _knn_split(x, 2),
+    "split3": lambda x: _knn_split(x, 3),
+    "incremental": _knn_incremental,
+}
+
+
+@pytest.mark.parametrize("path", sorted(_KNN_PATHS))
+@pytest.mark.parametrize("m,d", [(2, 3), (150, 3), (150, 8)])
+def test_knn_self_exclusion_matches_float64(path, m, d):
+    """Every kNN path excludes self by the masked min/argmin and keeps the
+    first occurrence on ties: indices and squared distances equal a
+    float64 brute force exactly, across tile boundaries, in the padded
+    tail, at m = 2, in the unrolled (d = 3) and the gemm (d = 8) body."""
+    x = _grid_rows(m, d)
+    ref_idx, ref_d2 = _knn_float64(x)
+    idx, d2 = _KNN_PATHS[path](x)
+    np.testing.assert_array_equal(idx, ref_idx)
+    np.testing.assert_array_equal(d2, ref_d2.astype(np.float32))
+    np.testing.assert_array_equal(
+        nearest_neighbors_legacy(x, block=64), ref_idx
+    )
+
+
+def _primitives(jaxpr) -> set[str]:
+    """Names of every primitive in ``jaxpr`` and its nested bodies."""
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                if hasattr(sub, "eqns"):
+                    names |= _primitives(sub)
+    return names
+
+
+def _knn_program(name: str):
+    """The jaxpr of one compiled kNN scan at a small shape."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.analytics.incremental import _rect_scan
+    from repro.analytics.pairwise import _fused_scan
+    from repro.analytics.split import _mesh_fn, _split_scan
+
+    xq = jnp.zeros((128, 8), jnp.float32)
+    m, s, zero = jnp.int32(100), jnp.float32(0.0), jnp.int32(0)
+    tiles = dict(task="knn", bq=64, bk=64)
+    if name == "fused":
+        return jax.make_jaxpr(partial(_fused_scan, **tiles))(xq, xq, m, s)
+    if name == "split":
+        x_sh = jnp.zeros((2, 64, 8), jnp.float32)
+        fn = partial(_split_scan, shards=2, **tiles)
+        return jax.make_jaxpr(fn)(xq, x_sh, m, s)
+    if name == "rect":
+        fn = partial(_rect_scan, **tiles)
+        return jax.make_jaxpr(fn)(xq, xq, m, s, zero, zero)
+    mesh = _mesh_fn(tuple(jax.devices()[:1]), 1, 1, "knn", 64, 64)
+    return jax.make_jaxpr(mesh)(xq, xq, m, s)
+
+
+@pytest.mark.parametrize("name", ["fused", "split", "rect", "mesh"])
+def test_knn_scan_has_no_sort(name):
+    """The kNN tile reduction is a masked min/argmin in every compiled
+    scan: no ``top_k`` or ``sort`` primitive anywhere in the program (the
+    walker does reach the tile body: its ``argmin`` is found)."""
+    prims = _primitives(_knn_program(name))
+    assert "argmin" in prims, sorted(prims)
+    assert not {"top_k", "sort"} & prims, sorted(prims)
 
 
 def test_knn_single_point_returns_self(xdup):

@@ -57,7 +57,7 @@ def test_devices_beyond_the_host_are_clamped():
 def test_each_mesh_fan_out_is_a_program_of_its_own_name(task):
     from repro.analytics.split import _mesh_fn
 
-    fn = _mesh_fn(tuple(jax.devices()[:1]), 1, 1, task, 64, 64, False)
+    fn = _mesh_fn(tuple(jax.devices()[:1]), 1, 1, task, 64, 64)
     x = np.zeros((128, 4), np.float32)
     text = fn.lower(x, x, np.int32(100), np.float32(0.5)).as_text()
     assert f"module @jit__mesh_{task} " in text

@@ -131,11 +131,10 @@ def test_e2e_demo_drop_chosen_and_faster(tmp_path):
     # term — building the (b, m) distance matrix is memory-bound and
     # identical at k=3 and k=25 — so on CPU the pca-vs-fft e2e gap is only
     # the O(m^2 k) matmul delta, thin enough for container timing noise to
-    # straddle. analytics/knn.py removes the second k-independent pass
-    # (self-exclusion) with top_k(2) on ACCELERATORS only: measured on
-    # XLA:CPU, lax.top_k is a 20-40x pessimization while where+argmin fuses
-    # into one pass anyway — the distance-matrix build itself is
-    # irreducible on every backend. The objective margin (cost-model
-    # ranking) stays wide and is asserted strictly above.
+    # straddle. Self-exclusion is a where+argmin that fuses into the same
+    # pass on every backend (a top_k(2) pass measured 20-40x slower on
+    # XLA:CPU and 12-18x slower per tile on a TPU v5e) — the
+    # distance-matrix build itself is irreducible on every backend. The objective margin
+    # (cost-model ranking) stays wide and is asserted strictly above.
     assert o["pca"].end_to_end_s < o["paa"].end_to_end_s, rep.summary()
     assert o["pca"].end_to_end_s < o["fft"].end_to_end_s * 1.05, rep.summary()
